@@ -160,14 +160,12 @@ def _triangle_section(g, n_vertices, failures, tag, n_mids=1000,
 
 
 def _kernel_section(g, seeds, failures) -> dict:
-    from repro.core import two_hop_counts
-    from repro.kernels.frontier_expand import HAVE_PALLAS
+    import jax
 
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        backend = "none"
+    from repro.core import two_hop_counts
+    from repro.kernels.common import default_interpret
+
+    backend = jax.default_backend()
 
     sparse = two_hop_counts(g, seeds, dense="never")
     dense = two_hop_counts(g, seeds, dense="kernel")  # builds + caches plan
@@ -181,7 +179,7 @@ def _kernel_section(g, seeds, failures) -> dict:
     out = {
         "n_seeds": int(seeds.shape[0]),
         "backend": backend,
-        "mosaic_kernel": bool(HAVE_PALLAS and backend == "tpu"),
+        "mosaic_kernel": not default_interpret(),
         "bitwise_equal": ok,
         "sparse_s": t_sparse,
         "dense_s": t_dense,  # plan memoized in the engine cache by now

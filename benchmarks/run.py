@@ -12,6 +12,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from . import (bench_disk, bench_fof, bench_insert, bench_linkbench,
                bench_multihop, bench_psw, bench_query, bench_service,
                bench_storage)
@@ -34,6 +36,7 @@ def main() -> None:
     ap.add_argument("--scale", type=float, default=1.0)
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [args.only] if args.only else list(SUITES)
     for name in names:

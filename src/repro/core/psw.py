@@ -16,9 +16,12 @@ Two execution engines:
    small state (`mode="dense_gather"`, the paper's §6.1.1 edge-centric model
    that keeps O(V) state in memory).
 
-The pure-jnp "virtual device" path (`plan.n_devices == 1`) computes the
-identical math with transposes standing in for the collectives, so all of it
-is testable on CPU; `shard_map` wiring is exercised by the dry-run.
+The pure-jnp "virtual device" path (`axis_name=None`) computes the identical
+math with transposes standing in for the collectives, so all of it is
+testable on CPU, and it is the only path any caller runs today
+(`snapshot()` -> `pagerank_device` on one chip). The collectives under
+`shard_map` (a v5e:2x2 mesh) have no caller yet; ROADMAP queue 2 holds
+that four-chip analytics path.
 """
 from __future__ import annotations
 
@@ -446,8 +449,9 @@ def edge_centric_sweep(
     mode: str = "psw_windows",
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
-    """Sweep over the whole DeviceGraph (virtual devices, or pass axis_name
-    under shard_map with pre-sliced arrays — see launch/sharding.py)."""
+    """Sweep over the whole DeviceGraph (virtual devices; under a caller's
+    shard_map, pass axis_name and per-shard arrays to
+    `edge_centric_sweep_arrays`)."""
     return edge_centric_sweep_arrays(
         dg.src, dg.dst_local, dg.mask, dg.interval_len, x, msg_fn,
         mode=mode, axis_name=axis_name, send_idx=dg.send_idx,

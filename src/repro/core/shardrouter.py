@@ -579,6 +579,11 @@ def _worker_main(shard_id: int, directory: str, sock_path: str,
 # ---------------------------------------------------------------------------
 # router
 # ---------------------------------------------------------------------------
+# a spawned child inherits os.environ as it stands at start(): this lock
+# keeps concurrent respawns from restoring each other's JAX_PLATFORMS
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
 class _ShardProc:
     """Router-side handle of one worker: process, socket path, and a
     generation counter — bumped on every respawn so threads' cached
@@ -773,11 +778,24 @@ class ShardRouter:
 
     # -- supervision -----------------------------------------------------------
     def _spawn(self, sp: _ShardProc) -> None:
+        """Start one worker. Workers are host-only (`supported_hop_modes`
+        is sparse), and a process that already holds an accelerator keeps
+        it: the child is started with JAX_PLATFORMS=cpu in its environment
+        so that it can never try to take the chip."""
         sp.proc = self._ctx.Process(
             target=_worker_main,
             args=(sp.shard_id, sp.dir, sp.sock_path, self.db_kw),
             name=f"graphdb-shard-{sp.shard_id}", daemon=True)
-        sp.proc.start()
+        with _SPAWN_ENV_LOCK:
+            prev = os.environ.get("JAX_PLATFORMS")
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            try:
+                sp.proc.start()
+            finally:
+                if prev is None:
+                    del os.environ["JAX_PLATFORMS"]
+                else:
+                    os.environ["JAX_PLATFORMS"] = prev
 
     def _wait_ready(self, sp: _ShardProc,
                     deadline: Optional[Deadline] = None) -> None:

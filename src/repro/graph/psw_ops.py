@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..jax_compat import mesh_axis_types, pvary, shard_map
+from jax import shard_map
+from jax.sharding import AxisType
 
 __all__ = ["ring_mesh", "ring_gather", "ring_scatter_sum", "local_gather",
            "local_scatter_sum", "local_edge_softmax"]
@@ -34,7 +35,7 @@ __all__ = ["ring_mesh", "ring_gather", "ring_scatter_sum", "local_gather",
 
 def ring_mesh(mesh: Mesh) -> Mesh:
     """1-D view of a production mesh (same devices, flattened)."""
-    return Mesh(mesh.devices.reshape(-1), ("ring",), **mesh_axis_types(1))
+    return Mesh(mesh.devices.reshape(-1), ("ring",), axis_types=(AxisType.Auto,))
 
 
 def _expand(sel, ndim):
@@ -47,9 +48,9 @@ def _expand(sel, ndim):
 def _ring_fwd_local(x_loc, idx_loc, *, P_size: int, n_loc: int):
     my = jax.lax.axis_index("ring")
     fwd_perm = [(j, (j + 1) % P_size) for j in range(P_size)]
-    out0 = pvary(
+    out0 = jax.lax.pcast(
         jnp.zeros((idx_loc.shape[0],) + x_loc.shape[1:], x_loc.dtype),
-        ("ring",))
+        ("ring",), to="varying")
 
     def step(carry, s):
         x_rot, out = carry
@@ -85,8 +86,8 @@ def _ring_bwd_local(idx_loc, g_loc, *, P_size: int, n_loc: int,
         gbuf = jax.lax.ppermute(gbuf, "ring", bwd_perm)
         return gbuf, None
 
-    gbuf0 = pvary(jnp.zeros((n_loc,) + feat_shape, jnp.float32),
-                  ("ring",))
+    gbuf0 = jax.lax.pcast(jnp.zeros((n_loc,) + feat_shape, jnp.float32),
+                          ("ring",), to="varying")
     gbuf, _ = jax.lax.scan(step, gbuf0, jnp.arange(P_size))
     return gbuf.astype(dtype)
 

@@ -6,64 +6,27 @@ slots (multi-hot layout). The PAL reversible hash (paper §7.2) spreads hot
 rows across table shards; within a shard this kernel does the positional
 lookup — the paper's 'edge position is the attribute key' discipline.
 
-Tiling: grid = (n_bag_blocks, n_dim_blocks). idx/weight tiles (Bb, K) are
-VMEM-resident; the table stays in ANY/HBM and rows stream in with one DMA
-per (bag, slot); weighted accumulation on the VPU. Padded slots carry
-weight 0 and index 0 (row 0 fetched, multiplied by zero).
+Tiling: grid = (n_bag_blocks,). idx/weight blocks sit in SMEM;
+the table stays in HBM and rows stream in with one DMA per (bag, slot);
+weighted float32 accumulation on the VPU — the shared row-DMA gather of
+kernels/frontier_expand. Padded slots carry weight 0 and index 0 (row 0
+fetched, multiplied by zero).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ..common import default_interpret
+from ..frontier_expand.frontier_expand import gather_rows_sum
 
 __all__ = ["embedding_bag_pallas"]
 
 
-def _kernel(idx_ref, w_ref, table_ref, o_ref, *, k_slots: int):
-    bb, db = o_ref.shape
-    d0 = pl.program_id(1) * db
-
-    def bag_body(b, acc):
-        def slot_body(k, acc):
-            r = idx_ref[b, k]
-            w = w_ref[b, k]
-            row = pl.load(table_ref, (pl.dslice(r, 1), pl.dslice(d0, db)))
-            return acc.at[b].add(w.astype(jnp.float32)
-                                 * row[0].astype(jnp.float32))
-
-        return jax.lax.fori_loop(0, k_slots, slot_body, acc)
-
-    acc0 = jnp.zeros((bb, db), jnp.float32)
-    o_ref[...] = jax.lax.fori_loop(0, bb, bag_body, acc0).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("bag_block", "dim_block",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("bag_block", "interpret"))
 def embedding_bag_pallas(idx, weights, table, *, bag_block: int = 128,
-                         dim_block: int = 128, interpret=None):
+                         interpret=None):
     """idx/weights: (B, K); table: (V, D). B % bag_block == 0,
-    D % dim_block == 0. Returns (B, D) weighted sums."""
-    if interpret is None:
-        interpret = default_interpret()
-    B, K = idx.shape
-    V, D = table.shape
-    assert B % bag_block == 0 and D % dim_block == 0
-
-    return pl.pallas_call(
-        functools.partial(_kernel, k_slots=K),
-        grid=(B // bag_block, D // dim_block),
-        in_specs=[
-            pl.BlockSpec((bag_block, K), lambda b, d: (b, 0)),
-            pl.BlockSpec((bag_block, K), lambda b, d: (b, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # table stays in HBM
-        ],
-        out_specs=pl.BlockSpec((bag_block, dim_block), lambda b, d: (b, d)),
-        out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
-        interpret=interpret,
-    )(idx, weights, table)
+    D % 128 == 0. Returns (B, D) weighted sums."""
+    return gather_rows_sum(idx, table, weights, r_block=bag_block,
+                           interpret=interpret, name="embedding_bag")

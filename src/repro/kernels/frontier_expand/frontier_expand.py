@@ -8,15 +8,20 @@ power-law degree tails. The kernel accumulates, per virtual row, the masked
 sum of frontier-indicator rows; the per-destination reduction over virtual
 rows happens outside (a sorted segment-sum keyed by the plan's `row_dst`).
 
-Tiling: grid = (n_row_blocks, n_frontier_blocks). idx/mask tiles (Br, K)
-sit in VMEM; the indicator panel x stays in ANY/HBM memory space and rows
-are fetched with dynamic loads (row DMAs on real TPU — source locality
-follows PAL's interval layout, same argument as segment_ell). The K slots
-of one virtual row are an unrolled masked-load loop on the VPU.
+Tiling: grid = (n_row_blocks,). The plan's mask is folded into idx (an
+empty slot holds -1) and one (Br·K,) block of it sits in SMEM, where the
+scalar core reads the DMA addresses. The indicator panel x stays in HBM
+(`pl.ANY`): per virtual row, every occupied slot starts one DMA of the
+whole row x[src] into its own VMEM buffer, then the row waits for its
+copies and adds them in slot order in float32. Empty slots issue no DMA.
+
+`gather_rows_sum` is that row-DMA gather with optional per-slot weights;
+the ELL (segment_ell) and EmbeddingBag kernels are thin wrappers over it.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,51 +30,88 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..common import default_interpret
 
-__all__ = ["frontier_expand_pallas"]
+__all__ = ["frontier_expand_pallas", "gather_rows_sum"]
+
+LANES = 128
 
 
-def _kernel(idx_ref, mask_ref, x_ref, o_ref, *, k_slots: int):
-    br, fb = o_ref.shape
-    f0 = pl.program_id(1) * fb
+def _kernel(*refs, k_slots: int, n_chunks: int, weighted: bool):
+    if weighted:
+        slots_ref, w_ref, x_hbm, o_ref, buf, sems = refs
+    else:
+        slots_ref, x_hbm, o_ref, buf, sems = refs
 
-    def row_body(i, acc):
-        # one row DMA per (virtual row, source) slot; masked slots add zero
-        def slot_body(k, acc):
-            r = idx_ref[i, k]
-            v = mask_ref[i, k]
-            row = pl.load(x_ref, (pl.dslice(r, 1), pl.dslice(f0, fb)))
-            contrib = jnp.where(v, row[0], jnp.zeros((fb,), o_ref.dtype))
-            return acc.at[i].add(contrib)
+    def copy(src, k):
+        return pltpu.make_async_copy(
+            x_hbm.at[pl.ds(src * n_chunks, n_chunks)], buf.at[k], sems.at[k])
 
-        return jax.lax.fori_loop(0, k_slots, slot_body, acc)
+    def row_body(i, carry):
+        base = i * k_slots
+        srcs = [slots_ref[base + k] for k in range(k_slots)]
+        for k, src in enumerate(srcs):
+            @pl.when(src >= 0)
+            def _start():
+                copy(src, k).start()
+        acc = jnp.zeros((n_chunks, LANES), jnp.float32)
+        for k, src in enumerate(srcs):
+            @pl.when(src >= 0)
+            def _wait():
+                copy(src, k).wait()
+            row = buf[k]
+            if weighted:
+                row = w_ref[base + k] * row
+            acc = acc + jnp.where(src >= 0, row, 0.0)
+        o_ref[pl.ds(i * n_chunks, n_chunks), :] = acc
+        return carry
 
-    acc0 = jnp.zeros(o_ref.shape, o_ref.dtype)
-    o_ref[...] = jax.lax.fori_loop(0, br, row_body, acc0)
+    jax.lax.fori_loop(0, o_ref.shape[0] // n_chunks, row_body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("r_block", "b_block",
-                                             "interpret"))
-def frontier_expand_pallas(idx, mask, x, *, r_block: int = 128,
-                           b_block: int = 128, interpret=None):
-    """idx/mask: (R, K) virtual-row source slots; x: (M, B) frontier
-    indicator panel. R % r_block == 0, B % b_block == 0. Returns (R, B)
-    per-virtual-row masked sums (pre-reduction)."""
+def gather_rows_sum(slots, x, weights: Optional[jax.Array] = None, *,
+                    r_block: int = 128, interpret=None,
+                    name: str = "gather_rows_sum"):
+    """out[r] = Σ_k weights[r, k] · x[slots[r, k]] over the slots that are
+    >= 0 (weight 1 without `weights`), accumulated in float32 in slot
+    order. slots/weights: (R, K); x: (M, B) with R % r_block == 0 and
+    B % 128 == 0. Returns (R, B) in x's dtype.
+
+    Mosaic DMAs whole (8, 128) tiles of a tiled HBM array, except from a
+    (M, 128) float32 array, whose rows it can copy one by one. So x goes
+    in as float32 rows of 128 lanes: a row of B lanes is B/128 consecutive
+    such rows, and the output comes back in the same layout."""
     if interpret is None:
         interpret = default_interpret()
-    R, K = idx.shape
-    B = x.shape[-1]
-    assert R % r_block == 0 and B % b_block == 0
+    R, K = slots.shape
+    M, B = x.shape
+    assert R % r_block == 0 and B % LANES == 0
+    n_chunks = B // LANES
 
-    grid = (R // r_block, B // b_block)
-    return pl.pallas_call(
-        functools.partial(_kernel, k_slots=K),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r_block, K), lambda r, b: (r, 0)),
-            pl.BlockSpec((r_block, K), lambda r, b: (r, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # x stays in HBM
-        ],
-        out_specs=pl.BlockSpec((r_block, b_block), lambda r, b: (r, b)),
-        out_shape=jax.ShapeDtypeStruct((R, B), x.dtype),
+    smem_block = pl.BlockSpec((r_block * K,), lambda r: (r,),
+                              memory_space=pltpu.SMEM)
+    operands = [slots.astype(jnp.int32).reshape(-1)]
+    if weights is not None:
+        operands.append(weights.astype(jnp.float32).reshape(-1))
+    out = pl.pallas_call(
+        functools.partial(_kernel, k_slots=K, n_chunks=n_chunks,
+                          weighted=weights is not None),
+        grid=(R // r_block,),
+        in_specs=[smem_block] * len(operands)
+        + [pl.BlockSpec(memory_space=pl.ANY)],       # x stays in HBM
+        out_specs=pl.BlockSpec((r_block * n_chunks, LANES), lambda r: (r, 0)),
+        out_shape=jax.ShapeDtypeStruct((R * n_chunks, LANES), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((K, n_chunks, LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((K,))],
         interpret=interpret,
-    )(idx, mask, x)
+        name=name,
+    )(*operands, x.astype(jnp.float32).reshape(M * n_chunks, LANES))
+    return out.reshape(R, B).astype(x.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("r_block", "interpret"))
+def frontier_expand_pallas(idx, mask, x, *, r_block: int = 128,
+                           interpret=None):
+    """idx/mask: (R, K) virtual-row source slots; x: (M, B) frontier
+    indicator panel. R % r_block == 0, B % 128 == 0. Returns (R, B)
+    per-virtual-row masked sums (pre-reduction)."""
+    return gather_rows_sum(jnp.where(mask, idx, -1), x, r_block=r_block,
+                           interpret=interpret, name="frontier_expand")

@@ -16,28 +16,15 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ..common import round_up
-from .ref import HAVE_JAX, frontier_expand_np, frontier_expand_ref
+from ..common import default_interpret, round_up
+from .frontier_expand import frontier_expand_pallas
+from .ref import frontier_expand_ref
 
-if HAVE_JAX:
-    import jax
-    import jax.numpy as jnp
-
-    try:
-        from .frontier_expand import frontier_expand_pallas
-
-        HAVE_PALLAS = True
-    except Exception:  # pragma: no cover - pallas missing from this jax
-        frontier_expand_pallas = None
-        HAVE_PALLAS = False
-else:  # pragma: no cover - exercised only without jax
-    frontier_expand_pallas = None
-    HAVE_PALLAS = False
-
-__all__ = ["FrontierPlan", "HAVE_PALLAS", "build_frontier_plan",
-           "frontier_expand_counts"]
+__all__ = ["FrontierPlan", "build_frontier_plan", "frontier_expand_counts"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,16 +90,11 @@ def frontier_expand_counts(plan: FrontierPlan, x, use_kernel=None,
     if use_kernel is None:
         # the Mosaic kernel is the TPU path; off-TPU it would run in
         # interpret mode (a correctness tool, ~1000x slow) — the jit'd ref
-        # K-loop is the honest device-less default
-        use_kernel = HAVE_PALLAS and jax.default_backend() == "tpu"
-    if not HAVE_JAX:
-        rows = frontier_expand_np(plan.idx, plan.mask, x)
-        out = np.zeros((plan.n_dst + 1, B), np.float32)
-        np.add.at(out, plan.row_dst, rows)
-        return out[:plan.n_dst]
+        # K-loop is the device-less default
+        use_kernel = not default_interpret()
     Bp = round_up(B, 128)
     xp = jnp.asarray(np.pad(x, ((0, 0), (0, Bp - B))))
-    if use_kernel and HAVE_PALLAS:
+    if use_kernel:
         rows = frontier_expand_pallas(jnp.asarray(plan.idx),
                                       jnp.asarray(plan.mask), xp,
                                       interpret=interpret)

@@ -1,20 +1,13 @@
-"""Oracle for the frontier-expansion kernel: jnp when available, else a
-numpy K-loop — multihop's dense path degrades gracefully to the same
-numbers without jax (the `jax_compat`-style fallback)."""
+"""Oracles for the frontier-expansion kernel: a jit'd jnp K-loop (the
+device-less default of `frontier_expand_counts`) and a plain numpy K-loop
+(the test oracle)."""
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-
-    HAVE_JAX = True
-except ImportError:  # pragma: no cover - exercised only without jax
-    jax = jnp = None
-    HAVE_JAX = False
-
-__all__ = ["HAVE_JAX", "frontier_expand_ref", "frontier_expand_np"]
+__all__ = ["frontier_expand_ref", "frontier_expand_np"]
 
 
 def frontier_expand_np(idx, mask, x):
@@ -26,19 +19,14 @@ def frontier_expand_np(idx, mask, x):
     return acc
 
 
-if HAVE_JAX:
+@jax.jit
+def frontier_expand_ref(idx, mask, x):
+    """idx/mask: (R, K); x: (M, B). out[r] = Σ_k mask[r,k]·x[idx[r,k]].
+    Same K-loop shape as the kernel (bounded memory at 1M+ edges)."""
+    def body(k, acc):
+        rows = x[jax.lax.dynamic_index_in_dim(idx, k, 1, False)]
+        m = jax.lax.dynamic_index_in_dim(mask, k, 1, False)
+        return acc + jnp.where(m[:, None], rows, 0)
 
-    @jax.jit
-    def frontier_expand_ref(idx, mask, x):
-        """idx/mask: (R, K); x: (M, B). out[r] = Σ_k mask[r,k]·x[idx[r,k]].
-        Same K-loop shape as the kernel (bounded memory at 1M+ edges)."""
-        def body(k, acc):
-            rows = x[jax.lax.dynamic_index_in_dim(idx, k, 1, False)]
-            m = jax.lax.dynamic_index_in_dim(mask, k, 1, False)
-            return acc + jnp.where(m[:, None], rows, 0)
-
-        acc0 = jnp.zeros((idx.shape[0], x.shape[1]), x.dtype)
-        return jax.lax.fori_loop(0, idx.shape[1], body, acc0)
-
-else:  # pragma: no cover - exercised only without jax
-    frontier_expand_ref = frontier_expand_np
+    acc0 = jnp.zeros((idx.shape[0], x.shape[1]), x.dtype)
+    return jax.lax.fori_loop(0, idx.shape[1], body, acc0)

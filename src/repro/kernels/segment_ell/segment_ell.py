@@ -2,70 +2,25 @@
 
 The PAL layout bounds per-vertex in-degree by |E|/P (paper §4.1 constraint),
 so a destination-node block's neighbor lists pad to a fixed K — the ELL
-format. The kernel streams (node_block × K) index tiles and accumulates
-masked gathered rows.
-
-Tiling: grid = (n_node_blocks, n_feat_blocks). Per step: idx/mask tiles
-(Bn, K) live in VMEM; the source-feature matrix stays in ANY/HBM memory
-space and rows are fetched with dynamic loads (on real TPU this lowers to
-row DMAs; PAL's window locality keeps the working set in a contiguous
-region — see DESIGN.md §2). Accumulation is an unrolled K-loop of masked
-row loads on the VPU.
+format. out[n] = Σ_k mask[n,k]·x[idx[n,k]] is exactly the frontier-expansion
+kernel's masked row sum, so this is that kernel under the ELL names: idx
+blocks in SMEM, x in HBM, one row DMA per occupied slot
+(kernels/frontier_expand/frontier_expand.py).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ..common import default_interpret
+from ..frontier_expand.frontier_expand import frontier_expand_pallas
 
 __all__ = ["segment_ell_pallas"]
 
 
-def _kernel(idx_ref, mask_ref, x_ref, o_ref, *, k_neighbors: int):
-    bn, fb = o_ref.shape
-    f0 = pl.program_id(1) * fb
-
-    def row_body(i, acc):
-        # one row DMA per (node, neighbor) slot; masked slots add zero
-        def slot_body(k, acc):
-            r = idx_ref[i, k]
-            v = mask_ref[i, k]
-            row = pl.load(x_ref, (pl.dslice(r, 1), pl.dslice(f0, fb)))
-            contrib = jnp.where(v, row[0], jnp.zeros((fb,), o_ref.dtype))
-            return acc.at[i].add(contrib)
-
-        return jax.lax.fori_loop(0, k_neighbors, slot_body, acc)
-
-    acc0 = jnp.zeros(o_ref.shape, o_ref.dtype)
-    o_ref[...] = jax.lax.fori_loop(0, bn, row_body, acc0)
-
-
-@functools.partial(jax.jit, static_argnames=("n_block", "f_block", "interpret"))
-def segment_ell_pallas(idx, mask, x, *, n_block: int = 128,
-                       f_block: int = 128, interpret=None):
-    """idx/mask: (N, K); x: (M, F). N % n_block == 0, F % f_block == 0.
+@functools.partial(jax.jit, static_argnames=("n_block", "interpret"))
+def segment_ell_pallas(idx, mask, x, *, n_block: int = 128, interpret=None):
+    """idx/mask: (N, K); x: (M, F). N % n_block == 0, F % 128 == 0.
     Returns (N, F) masked neighbor sums."""
-    if interpret is None:
-        interpret = default_interpret()
-    N, K = idx.shape
-    M, F = x.shape
-    assert N % n_block == 0 and F % f_block == 0
-
-    grid = (N // n_block, F // f_block)
-    return pl.pallas_call(
-        functools.partial(_kernel, k_neighbors=K),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n_block, K), lambda n, f: (n, 0)),
-            pl.BlockSpec((n_block, K), lambda n, f: (n, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # x stays in HBM
-        ],
-        out_specs=pl.BlockSpec((n_block, f_block), lambda n, f: (n, f)),
-        out_shape=jax.ShapeDtypeStruct((N, F), x.dtype),
-        interpret=interpret,
-    )(idx, mask, x)
+    return frontier_expand_pallas(idx, mask, x, r_block=n_block,
+                                  interpret=interpret)

@@ -6,8 +6,7 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
-
-from ..jax_compat import mesh_axis_types
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "TPU_V5E"]
 
@@ -24,4 +23,4 @@ TPU_V5E = {
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

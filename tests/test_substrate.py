@@ -62,7 +62,7 @@ class TestCompression:
     def test_compressed_psum_shardmap(self):
         # 1-device mesh still exercises the shard_map plumbing
         from jax.sharding import Mesh
-        from repro.jax_compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
         g = {"w": jnp.arange(8.0)}
