@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import operator
-import time
 from typing import Any, Optional, Sequence
 
 import numpy as np
@@ -48,7 +47,7 @@ from . import telemetry
 from .engine import StorageEngine, _expand_ranges, as_engine
 
 _M_HOPS = telemetry.counter("multihop.hops")
-_M_HOP_S = telemetry.histogram("multihop.hop.seconds")
+_M_H2D = telemetry.counter("multihop.kernel.h2d_bytes")
 
 GraphLike = Any
 
@@ -259,16 +258,33 @@ def _plan_cached(eng: StorageEngine, direction: str) -> bool:
             and ((_PLAN_KEY, direction), token) in eng.plan_cache())
 
 
+def _stage(plan, x):
+    """Hand one launch's operands to the device, counting the bytes (call
+    under the `multihop.kernel.prep` span, with the panel's build)."""
+    from ..kernels.frontier_expand import stage_frontier
+    staged, nbytes = stage_frontier(plan, x)
+    _M_H2D.inc(nbytes)
+    return staged
+
+
+def _launch(plan, staged) -> np.ndarray:
+    """Run one staged launch and read its counts back."""
+    from ..kernels.frontier_expand import expand_staged
+    with telemetry.span("multihop.kernel.wait"):
+        return expand_staged(plan, staged)
+
+
 def _expand_dense(eng: StorageEngine, frontier: np.ndarray,
                   direction: str) -> np.ndarray:
     """Kernel hop: scatter the frontier into a one-column indicator, run the
     frontier-expansion kernel, read back the touched destinations."""
-    from ..kernels.frontier_expand import frontier_expand_counts
     plan = dense_plan(eng, direction)
     iv = eng.intervals
-    x = np.zeros((eng.n_internal_vertices, 1), np.float32)
-    x[np.asarray(iv.to_internal(frontier), np.int64), 0] = 1.0
-    counts = frontier_expand_counts(plan, x)
+    with telemetry.span("multihop.kernel.prep"):
+        x = np.zeros((eng.n_internal_vertices, 1), np.float32)
+        x[np.asarray(iv.to_internal(frontier), np.int64), 0] = 1.0
+        staged = _stage(plan, x)
+    counts = _launch(plan, staged)
     nxt = np.flatnonzero(counts[:, 0] > 0)
     return np.sort(np.asarray(iv.to_original(nxt), np.int64))
 
@@ -337,21 +353,22 @@ def khop(g: GraphLike, seeds, k: int, direction: str = "out",
                          predicate)
         with telemetry.span("multihop.hop", hop=hop, mode=mode,
                             frontier=int(frontier.shape[0])) as sp:
-            t0 = time.perf_counter()
             if mode == "kernel":
                 nxt = _expand_dense(eng, frontier, direction)
             elif mode == "stream":
                 nxt = _expand_stream(eng, frontier, direction)
             else:
-                _, nb = eng.expand_frontier(frontier, direction, predicate)
-                nxt = np.unique(nb)
-            fresh = _setdiff_sorted(nxt, visited)
+                with telemetry.span("multihop.probe"):
+                    _, nb = eng.expand_frontier(frontier, direction,
+                                                predicate)
+                    nxt = np.unique(nb)
+            with telemetry.span("multihop.merge"):
+                fresh = _setdiff_sorted(nxt, visited)
+                visited = _union_sorted(visited, fresh)
             sp.tag(fresh=int(fresh.shape[0]))
             _M_HOPS.inc(label=mode)
-            _M_HOP_S.observe(time.perf_counter() - t0)
         if fresh.shape[0] == 0:
             break
-        visited = _union_sorted(visited, fresh)
         levels.append(fresh)
         frontier = fresh
     return KHopResult(levels, visited)
@@ -451,7 +468,6 @@ def _two_hop_dense(eng: StorageEngine, seeds: np.ndarray, direction: str,
     the distinct-friend panel, hop 2's accumulation IS the distinct-middle
     count (float32 counts are integer-exact far below 2**24). Seeds stream
     through in `_SEED_BLOCK`-column panels — one kernel feature tile."""
-    from ..kernels.frontier_expand import frontier_expand_counts
     plan = dense_plan(eng, direction)
     iv = eng.intervals
     M = np.int64(eng.n_internal_vertices)
@@ -460,10 +476,14 @@ def _two_hop_dense(eng: StorageEngine, seeds: np.ndarray, direction: str,
     sk_parts, cnt_parts, fk_parts = [], [], []
     for c0 in range(0, S, _SEED_BLOCK):
         blk = si[c0:c0 + _SEED_BLOCK]
-        x = np.zeros((int(M), blk.shape[0]), np.float32)
-        x[blk, np.arange(blk.shape[0])] = 1.0
-        c1 = frontier_expand_counts(plan, x)            # (M, B) 0/1: edges
-        c2 = frontier_expand_counts(plan, (c1 > 0).astype(np.float32))
+        with telemetry.span("multihop.kernel.prep"):
+            x = np.zeros((int(M), blk.shape[0]), np.float32)
+            x[blk, np.arange(blk.shape[0])] = 1.0
+            staged = _stage(plan, x)
+        c1 = _launch(plan, staged)                      # (M, B) 0/1: edges
+        with telemetry.span("multihop.kernel.prep"):
+            staged = _stage(plan, (c1 > 0).astype(np.float32))
+        c2 = _launch(plan, staged)
         w, j = np.nonzero(c2)
         cnt_parts.append(np.rint(c2[w, j]).astype(np.int64))
         wo = np.asarray(iv.to_original(w), np.int64)

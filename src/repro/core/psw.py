@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from .lsm import LSMTree
 from .pal import GraphPAL, IntervalMap
 
@@ -462,16 +463,19 @@ def edge_centric_sweep(
 def pagerank_device(dg: DeviceGraph, n_iters: int = 5, damping: float = 0.85,
                     mode: str = "psw_windows",
                     axis_name: Optional[str] = None) -> jnp.ndarray:
-    """PageRank with the device PSW engine. Returns (P, L) ranks."""
-    P, L = dg.n_partitions, dg.interval_len
-    inv_deg = 1.0 / jnp.maximum(dg.outdeg.astype(jnp.float32), 1.0)
+    """PageRank with the device PSW engine. Returns (P, L) ranks, as soon
+    as the scan is enqueued (the `psw.pagerank` span ends there)."""
+    with telemetry.span("psw.pagerank"):
+        P, L = dg.n_partitions, dg.interval_len
+        inv_deg = 1.0 / jnp.maximum(dg.outdeg.astype(jnp.float32), 1.0)
 
-    def body(r, _):
-        contrib = (r * inv_deg)[..., None]           # (P, L, 1)
-        acc = edge_centric_sweep(dg, contrib, lambda s: s, mode, axis_name)
-        r_new = (1.0 - damping) + damping * acc[..., 0]
-        return r_new, None
+        def body(r, _):
+            contrib = (r * inv_deg)[..., None]           # (P, L, 1)
+            acc = edge_centric_sweep(dg, contrib, lambda s: s, mode,
+                                     axis_name)
+            r_new = (1.0 - damping) + damping * acc[..., 0]
+            return r_new, None
 
-    r0 = jnp.ones((P, L), jnp.float32)
-    r, _ = jax.lax.scan(body, r0, None, length=n_iters)
+        r0 = jnp.ones((P, L), jnp.float32)
+        r, _ = jax.lax.scan(body, r0, None, length=n_iters)
     return r

@@ -29,7 +29,12 @@ Design constraints, in order:
 Spans are Chrome-trace complete events (`ph: "X"`): wall-clock `ts` in
 microseconds (epoch-based, so router and worker processes align on one
 Perfetto timeline), `dur` from a monotonic clock, `pid`/`tid` real OS ids,
-and `args` carrying `trace`/`span`/`parent` ids plus caller tags. Context
+and `args` carrying `trace`/`span`/`parent` ids plus caller tags. Each span
+also holds a `jax.profiler.TraceAnnotation` of its name (no tags) open for
+as long as it lasts, so under the JAX profiler it lands on the profiler's
+host plane, on the clock of the device trace: device idle time can then be
+put down to the program span the host was in. With no profiler running that
+costs one annotation open and close per span; disabled, none. Context
 propagates through a thread-local stack; `current_context()` exports the
 ambient (trace, span) pair as a JSON-safe list that rides in shard RPC
 frame metadata and into maintenance-pool submissions, and `attach()`
@@ -126,8 +131,20 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "lsm.purged_tombstones": ("counter", "tombstones purged by merges"),
     # --- multihop (core/multihop.py) ---
     "multihop.hops": ("counter", "frontier expansions, by mode label"),
-    "multihop.hop.seconds": ("histogram", "single-hop expansion latency"),
     "multihop.hop": ("span", "one k-hop frontier expansion"),
+    "multihop.probe": ("span",
+                       "a sparse hop's host slab probes and their dedup"),
+    "multihop.kernel.prep": ("span",
+                             "one kernel launch's indicator build, lane "
+                             "padding and upload of every operand"),
+    "multihop.kernel.wait": ("span",
+                             "one kernel + segment_sum launch up to the "
+                             "blocking read-back of the counts"),
+    "multihop.kernel.h2d_bytes": ("counter",
+                                  "bytes of host arrays handed to the "
+                                  "device by frontier-expansion launches"),
+    "multihop.merge": ("span",
+                       "one hop's visited-set subtraction and union"),
     "multihop.two_hop": ("span", "one batched FoF (two_hop_counts) call"),
     # --- shard runtime (core/shardrouter.py) ---
     "shard.rpc.requests": ("counter", "router-side RPC calls, by op label"),
@@ -177,6 +194,10 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                                 "request queue delay, enqueue to batch "
                                 "start"),
     "frontdesk.depth": ("gauge", "requests queued at the front desk now"),
+    # --- device analytics (core/psw.py) ---
+    "psw.pagerank": ("span",
+                     "one pagerank_device call up to the enqueue of its "
+                     "scan (dispatch, trace, program load)"),
 }
 
 _SPAN_NAMES = frozenset(n for n, (k, _) in CATALOG.items() if k == "span")
@@ -405,6 +426,17 @@ def _hist_dict(buckets: np.ndarray, total: float) -> Dict[str, Any]:
 # trace spans — thread-local context, Chrome-trace complete events
 # ---------------------------------------------------------------------------
 _ctx = threading.local()
+_TRACE_ANNOTATION = None   # jax.profiler.TraceAnnotation, resolved lazily
+
+
+def _trace_annotation():
+    """The profiler's host-span class, imported on the first span so that
+    importing telemetry costs no JAX import."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
 
 
 def _new_id() -> str:
@@ -650,7 +682,8 @@ def reset() -> None:
 
 @contextmanager
 def span(name: str, **tags):
-    """Record a Chrome-trace complete event around the body.
+    """Record a Chrome-trace complete event around the body, and hold a
+    profiler annotation of `name` open over it (module docstring).
 
     Joins the ambient trace if one exists (same thread via the context
     stack, or a remote one re-established by `attach`); otherwise roots a
@@ -672,12 +705,15 @@ def span(name: str, **tags):
     span_id = _new_id()
     handle = SpanHandle(name, trace_id, span_id, parent, dict(tags))
     stack.append((trace_id, span_id))
+    annotation = _trace_annotation()(name)
+    annotation.__enter__()
     ts_us = time.time_ns() // 1000
     t0 = time.perf_counter_ns()
     try:
         yield handle
     finally:
         dur_us = (time.perf_counter_ns() - t0) // 1000
+        annotation.__exit__(None, None, None)
         stack.pop()
         args = _safe_tags(handle.tags)
         args["trace"] = trace_id

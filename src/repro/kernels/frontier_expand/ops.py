@@ -24,7 +24,8 @@ from ..common import default_interpret, round_up
 from .frontier_expand import frontier_expand_pallas
 from .ref import frontier_expand_ref
 
-__all__ = ["FrontierPlan", "build_frontier_plan", "frontier_expand_counts"]
+__all__ = ["FrontierPlan", "StagedFrontier", "build_frontier_plan",
+           "expand_staged", "frontier_expand_counts", "stage_frontier"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,31 +80,57 @@ def build_frontier_plan(src, dst, n_src: int, n_dst: int,
                         k_slots)
 
 
+@dataclasses.dataclass(frozen=True)
+class StagedFrontier:
+    """One launch's operands on the device (`stage_frontier`)."""
+
+    idx: jax.Array        # the plan's (R, K) slots
+    mask: jax.Array
+    row_dst: jax.Array
+    x: jax.Array          # (n_src, Bp) indicator panel, padded to lanes
+    n_cols: int           # B, the panel's useful columns
+
+
+def stage_frontier(plan: FrontierPlan, x):
+    """First half of `frontier_expand_counts`: pad the (n_src, B) panel to
+    whole 128-lane tiles and hand it and the plan to the device, waiting
+    until every upload has landed. Returns the staged operands and the
+    bytes of the host arrays handed over."""
+    x = np.ascontiguousarray(np.asarray(x, np.float32))
+    B = x.shape[1]
+    host = (plan.idx, plan.mask, plan.row_dst,
+            np.pad(x, ((0, 0), (0, round_up(B, 128) - B))))
+    dev = jax.block_until_ready([jnp.asarray(a) for a in host])
+    return StagedFrontier(*dev, n_cols=B), sum(a.nbytes for a in host)
+
+
+def expand_staged(plan: FrontierPlan, staged: StagedFrontier,
+                  use_kernel=None, interpret=None) -> np.ndarray:
+    """Second half of `frontier_expand_counts`: launch the expansion and
+    the segment-sum over staged operands and read the counts back."""
+    if use_kernel is None:
+        # the Mosaic kernel is the TPU path; off-TPU it would run in
+        # interpret mode (a correctness tool, ~1000x slow) — the jit'd ref
+        # K-loop is the device-less default
+        use_kernel = not default_interpret()
+    if use_kernel:
+        rows = frontier_expand_pallas(staged.idx, staged.mask, staged.x,
+                                      interpret=interpret)
+    else:
+        rows = frontier_expand_ref(staged.idx, staged.mask, staged.x)
+    # virtual rows are destination-sorted; padding rows land in segment
+    # n_dst and are sliced away
+    seg = jax.ops.segment_sum(rows, staged.row_dst,
+                              num_segments=plan.n_dst + 1,
+                              indices_are_sorted=True)
+    return np.asarray(seg[:plan.n_dst, :staged.n_cols])
+
+
 def frontier_expand_counts(plan: FrontierPlan, x, use_kernel=None,
                            interpret=None) -> np.ndarray:
     """out (n_dst, B): out[d, j] = Σ_{(s,d) in plan} x[s, j]. With 0/1
     indicator columns this is each destination's count of DISTINCT frontier
     in-neighbors — expand + distinct + aggregate in one launch. float32
     accumulation is integer-exact below 2**24, far above any degree here."""
-    x = np.ascontiguousarray(np.asarray(x, np.float32))
-    B = x.shape[1]
-    if use_kernel is None:
-        # the Mosaic kernel is the TPU path; off-TPU it would run in
-        # interpret mode (a correctness tool, ~1000x slow) — the jit'd ref
-        # K-loop is the device-less default
-        use_kernel = not default_interpret()
-    Bp = round_up(B, 128)
-    xp = jnp.asarray(np.pad(x, ((0, 0), (0, Bp - B))))
-    if use_kernel:
-        rows = frontier_expand_pallas(jnp.asarray(plan.idx),
-                                      jnp.asarray(plan.mask), xp,
-                                      interpret=interpret)
-    else:
-        rows = frontier_expand_ref(jnp.asarray(plan.idx),
-                                   jnp.asarray(plan.mask), xp)
-    # virtual rows are destination-sorted; padding rows land in segment
-    # n_dst and are sliced away
-    seg = jax.ops.segment_sum(rows, jnp.asarray(plan.row_dst),
-                              num_segments=plan.n_dst + 1,
-                              indices_are_sorted=True)
-    return np.asarray(seg[:plan.n_dst, :B])
+    staged, _ = stage_frontier(plan, x)
+    return expand_staged(plan, staged, use_kernel, interpret)

@@ -26,6 +26,7 @@ from repro.core import (
     two_hop_counts,
 )
 from repro.core import multihop as mh
+from repro.core import telemetry
 
 
 # ---------------------------------------------------------------------------
@@ -381,3 +382,28 @@ class TestFrontierPlan:
         plan = build_frontier_plan(np.empty(0), np.empty(0), 10, 10)
         out = frontier_expand_counts(plan, np.ones((10, 2), np.float32))
         assert out.shape == (10, 2) and not out.any()
+
+
+class TestKernelUploadCounter:
+    @pytest.mark.parametrize("query", ["khop", "two_hop"])
+    def test_h2d_bytes_are_the_staged_arrays(self, query):
+        """`multihop.kernel.h2d_bytes` adds, per launch, the `.nbytes` of
+        the plan's arrays and of the indicator panel padded to 128 lanes."""
+        t = build_messy_lsm(110, 400, 3)
+        plan = mh.dense_plan(t, "out")
+        M = as_engine(t).n_internal_vertices
+        per_launch = (plan.idx.nbytes + plan.mask.nbytes
+                      + plan.row_dst.nbytes + M * 128 * 4)
+        before = telemetry.snapshot()["counters"]
+        if query == "khop":
+            khop(t, [5], 4, dense="kernel")
+        else:
+            two_hop_counts(t, np.arange(10), dense="kernel")
+        after = telemetry.snapshot()["counters"]
+        launches = (after["multihop.hops"].get("kernel", 0)
+                    - before.get("multihop.hops", {}).get("kernel", 0)
+                    if query == "khop" else 2)
+        assert launches > 0
+        sent = (after["multihop.kernel.h2d_bytes"]
+                - before.get("multihop.kernel.h2d_bytes", 0))
+        assert sent == launches * per_launch

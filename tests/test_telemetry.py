@@ -214,6 +214,105 @@ class TestSpans:
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's host plane
+# ---------------------------------------------------------------------------
+PROGRAM_PREFIXES = ("multihop.", "psw.")
+HOP_CHILDREN = ("multihop.probe", "multihop.kernel.prep",
+                "multihop.kernel.wait", "multihop.merge")
+
+
+def _tiny_store():
+    from repro.core import IntervalMap, LSMTree, dense_plan
+    rng = np.random.default_rng(5)
+    t = LSMTree(IntervalMap.for_capacity(199, 16), n_levels=3, branching=4,
+                buffer_cap=400, max_partition_edges=800)
+    t.insert_edges(rng.integers(0, 200, 3000), rng.integers(0, 200, 3000))
+    dense_plan(t, "out")   # held: hops over the threshold run the kernel
+    return t
+
+
+def _run_khop(t):
+    from repro.core import khop
+    res = khop(t, [3], 6)
+    assert len(res.levels) > 2
+
+
+def _run_pagerank(t):
+    from repro.core.psw import build_device_graph, pagerank_device
+    pagerank_device(build_device_graph(t), n_iters=2).block_until_ready()
+
+
+def _profiled(tmp_path, fn):
+    """Run `fn` under the JAX profiler: the program spans in the ring,
+    and the program's events on the host plane of the recorded xplane."""
+    import jax
+    from jax.profiler import ProfileData
+    telemetry.trace_events(clear=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    ring = [e["name"] for e in telemetry.trace_events(clear=True)
+            if e["name"].startswith(PROGRAM_PREFIXES)]
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    host = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(PROGRAM_PREFIXES)]
+    return ring, host
+
+
+class TestProfilerSpans:
+    @pytest.mark.parametrize("on", [True, False])
+    def test_span_opens_one_annotation_of_its_name(self, monkeypatch, on):
+        opened = []
+
+        class Recorder:
+            def __init__(self, name, **kw):
+                assert not kw   # the name only: tags stay on the ring
+                self.name = name
+
+            def __enter__(self):
+                opened.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                opened.append(("exit", self.name))
+
+        monkeypatch.setattr(telemetry, "_TRACE_ANNOTATION", Recorder)
+        telemetry.set_enabled(on)
+        try:
+            with telemetry.span("x.outer", tag=1):
+                with telemetry.span("x.inner"):
+                    pass
+        finally:
+            telemetry.set_enabled(True)
+        want = [("enter", "x.outer"), ("enter", "x.inner"),
+                ("exit", "x.inner"), ("exit", "x.outer")]
+        assert opened == (want if on else [])
+
+    @pytest.mark.parametrize("run,spans,parents", [
+        (_run_khop, {"multihop.hop", *HOP_CHILDREN},
+         {c: "multihop.hop" for c in HOP_CHILDREN}),
+        (_run_pagerank, {"psw.pagerank"}, {}),
+    ], ids=["khop", "pagerank"])
+    def test_program_spans_land_on_the_host_plane(self, tmp_path, run,
+                                                  spans, parents):
+        t = _tiny_store()
+        ring, host = _profiled(tmp_path, lambda: run(t))
+        names = [n for _, _, n in host]
+        # as many events on the host plane as the ring holds, by name
+        assert sorted(names) == sorted(ring)
+        assert set(names) == spans
+        # nested as in the code: each child lies inside a parent event
+        for a, b, n in host:
+            if n in parents:
+                assert any(pa <= a and b <= pb
+                           for pa, pb, pn in host if pn == parents[n]), n
+
+
+# ---------------------------------------------------------------------------
 # ServiceDB integration
 # ---------------------------------------------------------------------------
 class TestServiceIntegration:
