@@ -46,8 +46,8 @@ from repro.core import (FrontDesk, ServiceDB, ShardRouter,  # noqa: E402
                         dense_plan, khop, pagerank_device, two_hop_counts)
 from repro.core.query import consistent_engine  # noqa: E402
 from repro.kernels.common import default_interpret  # noqa: E402
-from repro.kernels.frontier_expand.frontier_expand import \
-    frontier_expand_pallas  # noqa: E402
+from repro.kernels.frontier_expand import \
+    frontier_expand_launch  # noqa: E402
 
 # twitter-2010 (Kwak et al., WWW'10), the paper's largest graph
 TWITTER_VERTICES = 41_652_230
@@ -153,10 +153,13 @@ def device_multihop(svc, seeds, k: int = 3) -> dict:
         out["plan_edges"] = plan.n_edges
 
         x = jax.ShapeDtypeStruct((plan.n_src, SEED_BLOCK), jnp.float32)
-        idx = jax.ShapeDtypeStruct(plan.idx.shape, jnp.int32)
-        mask = jax.ShapeDtypeStruct(plan.mask.shape, jnp.bool_)
+        slots = jax.ShapeDtypeStruct((plan.idx.size,), jnp.int32)
+        row_dst = jax.ShapeDtypeStruct(plan.row_dst.shape, jnp.int32)
         t0 = time.perf_counter()
-        lowered = frontier_expand_pallas.lower(idx, mask, x)
+        # the hop's own program, as `expand_staged` launches it here
+        lowered = frontier_expand_launch.lower(
+            slots, row_dst, x, n_dst=plan.n_dst,
+            use_kernel=not default_interpret())
         lowered.compile()
         out["kernel_compile_s"] = time.perf_counter() - t0
         out["mosaic_custom_call"] = "tpu_custom_call" in lowered.as_text()

@@ -26,10 +26,10 @@ Dense frontiers additionally get a device path: a `FrontierPlan`
 (kernels/frontier_expand) lays the store's deduplicated edge set out as
 virtual-row ELL tiles and a Pallas kernel expands indicator columns on the
 accelerator; `khop(dense="auto")` picks sparse probes, a bottom-up edge
-stream, or the kernel by frontier density (§10.3). Plans and packed edge-key
-sets are memoized on the engine's `plan_cache()` keyed by `cache_token()`,
-so a `ManifestView` shares them across every reader of one publication and
-a mutated store can never serve a stale plan.
+stream, or the kernel by frontier density (§10.3). Plans, their device
+copies and packed edge-key sets are memoized on the engine's `plan_cache()`
+keyed by `cache_token()`, so a `ManifestView` shares them across every
+reader of one publication and a mutated store can never serve a stale plan.
 
 All operators speak only the `StorageEngine` protocol — they run identically
 on a live `LSMTree`, a bulk `GraphPAL`, an mmap-backed `GraphDB`, and a
@@ -48,6 +48,7 @@ from .engine import StorageEngine, _expand_ranges, as_engine
 
 _M_HOPS = telemetry.counter("multihop.hops")
 _M_H2D = telemetry.counter("multihop.kernel.h2d_bytes")
+_M_PLAN_UPLOADS = telemetry.counter("multihop.kernel.plan_uploads")
 
 GraphLike = Any
 
@@ -199,10 +200,11 @@ def _expand_stream(eng: StorageEngine, frontier: np.ndarray,
 # Dense path: virtual-row ELL plan + Pallas frontier-expansion kernel
 # ---------------------------------------------------------------------------
 _PLAN_KEY = "multihop:dense_plan"
+_DEVICE_PLAN_KEY = "multihop:dense_plan_device"
 _EDGE_KEYS = "multihop:edge_keys"
 
 
-def _memoized(eng: StorageEngine, name: str, builder):
+def _memoized(eng: StorageEngine, name, builder):
     token = eng.cache_token()
     if token is None:
         return builder()
@@ -210,6 +212,11 @@ def _memoized(eng: StorageEngine, name: str, builder):
     key = (name, token)
     val = cache.get(key)
     if val is None:
+        # a new token means the store moved on: no reader can ask for an
+        # older token's entry again, so drop it (a device plan holds HBM)
+        for old in [k for k in list(cache) if isinstance(k, tuple)
+                    and len(k) == 2 and k[0] == name]:
+            cache.pop(old, None)
         val = cache[key] = builder()
     return val
 
@@ -258,20 +265,33 @@ def _plan_cached(eng: StorageEngine, direction: str) -> bool:
             and ((_PLAN_KEY, direction), token) in eng.plan_cache())
 
 
-def _stage(plan, x):
-    """Hand one launch's operands to the device, counting the bytes (call
-    under the `multihop.kernel.prep` span, with the panel's build)."""
+def _device_plan(eng: StorageEngine, plan, direction: str):
+    """`plan` on the device: uploaded at the first kernel launch over it
+    and memoized beside it, under the same token (call under the
+    `multihop.kernel.prep` span)."""
+    def build():
+        from ..kernels.frontier_expand import upload_plan
+        dplan = upload_plan(plan)
+        _M_H2D.inc(dplan.nbytes)
+        _M_PLAN_UPLOADS.inc()
+        return dplan
+    return _memoized(eng, (_DEVICE_PLAN_KEY, direction), build)
+
+
+def _stage(x):
+    """Hand one launch's indicator panel to the device, counting the bytes
+    (call under the `multihop.kernel.prep` span, with the panel's build)."""
     from ..kernels.frontier_expand import stage_frontier
-    staged, nbytes = stage_frontier(plan, x)
+    staged, nbytes = stage_frontier(x)
     _M_H2D.inc(nbytes)
     return staged
 
 
-def _launch(plan, staged) -> np.ndarray:
+def _launch(dplan, staged) -> np.ndarray:
     """Run one staged launch and read its counts back."""
     from ..kernels.frontier_expand import expand_staged
     with telemetry.span("multihop.kernel.wait"):
-        return expand_staged(plan, staged)
+        return expand_staged(dplan, staged)
 
 
 def _expand_dense(eng: StorageEngine, frontier: np.ndarray,
@@ -281,10 +301,11 @@ def _expand_dense(eng: StorageEngine, frontier: np.ndarray,
     plan = dense_plan(eng, direction)
     iv = eng.intervals
     with telemetry.span("multihop.kernel.prep"):
+        dplan = _device_plan(eng, plan, direction)
         x = np.zeros((eng.n_internal_vertices, 1), np.float32)
         x[np.asarray(iv.to_internal(frontier), np.int64), 0] = 1.0
-        staged = _stage(plan, x)
-    counts = _launch(plan, staged)
+        staged = _stage(x)
+    counts = _launch(dplan, staged)
     nxt = np.flatnonzero(counts[:, 0] > 0)
     return np.sort(np.asarray(iv.to_original(nxt), np.int64))
 
@@ -477,13 +498,14 @@ def _two_hop_dense(eng: StorageEngine, seeds: np.ndarray, direction: str,
     for c0 in range(0, S, _SEED_BLOCK):
         blk = si[c0:c0 + _SEED_BLOCK]
         with telemetry.span("multihop.kernel.prep"):
+            dplan = _device_plan(eng, plan, direction)
             x = np.zeros((int(M), blk.shape[0]), np.float32)
             x[blk, np.arange(blk.shape[0])] = 1.0
-            staged = _stage(plan, x)
-        c1 = _launch(plan, staged)                      # (M, B) 0/1: edges
+            staged = _stage(x)
+        c1 = _launch(dplan, staged)                     # (M, B) 0/1: edges
         with telemetry.span("multihop.kernel.prep"):
-            staged = _stage(plan, (c1 > 0).astype(np.float32))
-        c2 = _launch(plan, staged)
+            staged = _stage((c1 > 0).astype(np.float32))
+        c2 = _launch(dplan, staged)
         w, j = np.nonzero(c2)
         cnt_parts.append(np.rint(c2[w, j]).astype(np.int64))
         wo = np.asarray(iv.to_original(w), np.int64)

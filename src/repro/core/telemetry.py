@@ -135,14 +135,19 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "multihop.probe": ("span",
                        "a sparse hop's host slab probes and their dedup"),
     "multihop.kernel.prep": ("span",
-                             "one kernel launch's indicator build, lane "
-                             "padding and upload of every operand"),
+                             "one kernel launch's indicator build and "
+                             "upload, plus the plan's upload at the first "
+                             "launch over it"),
     "multihop.kernel.wait": ("span",
                              "one kernel + segment_sum launch up to the "
                              "blocking read-back of the counts"),
     "multihop.kernel.h2d_bytes": ("counter",
                                   "bytes of host arrays handed to the "
                                   "device by frontier-expansion launches"),
+    "multihop.kernel.plan_uploads": ("counter",
+                                     "frontier-expansion plans uploaded to "
+                                     "the device, once per store content "
+                                     "and direction"),
     "multihop.merge": ("span",
                        "one hop's visited-set subtraction and union"),
     "multihop.two_hop": ("span", "one batched FoF (two_hop_counts) call"),

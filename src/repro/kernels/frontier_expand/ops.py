@@ -15,17 +15,19 @@ virtual rows and discards padding.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..common import default_interpret, round_up
-from .frontier_expand import frontier_expand_pallas
+from .frontier_expand import LANES, gather_rows_sum
 from .ref import frontier_expand_ref
 
-__all__ = ["FrontierPlan", "StagedFrontier", "build_frontier_plan",
-           "expand_staged", "frontier_expand_counts", "stage_frontier"]
+__all__ = ["DevicePlan", "FrontierPlan", "build_frontier_plan",
+           "expand_staged", "frontier_expand_counts",
+           "frontier_expand_launch", "stage_frontier", "upload_plan"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,49 +83,72 @@ def build_frontier_plan(src, dst, n_src: int, n_dst: int,
 
 
 @dataclasses.dataclass(frozen=True)
-class StagedFrontier:
-    """One launch's operands on the device (`stage_frontier`)."""
+class DevicePlan:
+    """A plan's operands resident on the device (`upload_plan`)."""
 
-    idx: jax.Array        # the plan's (R, K) slots
-    mask: jax.Array
-    row_dst: jax.Array
-    x: jax.Array          # (n_src, Bp) indicator panel, padded to lanes
-    n_cols: int           # B, the panel's useful columns
+    slots: jax.Array      # (R·K,) int32 source per slot, -1 where empty
+    row_dst: jax.Array    # (R,) int32 destination per row
+    n_dst: int
+    nbytes: int           # bytes of the host arrays handed over
 
 
-def stage_frontier(plan: FrontierPlan, x):
-    """First half of `frontier_expand_counts`: pad the (n_src, B) panel to
-    whole 128-lane tiles and hand it and the plan to the device, waiting
-    until every upload has landed. Returns the staged operands and the
-    bytes of the host arrays handed over."""
-    x = np.ascontiguousarray(np.asarray(x, np.float32))
+def upload_plan(plan: FrontierPlan) -> DevicePlan:
+    """Fold the plan's mask into its slots (an empty slot holds -1) and
+    hand the slots and `row_dst` to the device, waiting until they have
+    landed. A caller that keeps the result launches any number of
+    expansions over the plan without sending it again. The slots go flat,
+    as the kernel reads them: a (R, K) int32 array of K < 128 would be
+    laid out in padded lane tiles and copied flat at every launch."""
+    host = (np.where(plan.mask, plan.idx, np.int32(-1)).reshape(-1),
+            plan.row_dst)
+    slots, row_dst = jax.block_until_ready([jnp.asarray(a) for a in host])
+    return DevicePlan(slots, row_dst, plan.n_dst,
+                      sum(a.nbytes for a in host))
+
+
+def stage_frontier(x):
+    """Hand one launch's (n_src, B) indicator panel to the device as it is,
+    unpadded, waiting until it has landed. Returns the device panel and
+    the bytes of the host array handed over."""
+    x = np.ascontiguousarray(x, np.float32)
+    return jax.block_until_ready(jnp.asarray(x)), x.nbytes
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_dst", "use_kernel", "interpret"))
+def frontier_expand_launch(slots, row_dst, x, *, n_dst: int,
+                           use_kernel: bool, interpret=None):
+    """One expansion as one program: (n_dst, B) counts of the (n_src, B)
+    panel x over a device plan's flat slots and `row_dst`. The kernel
+    takes whole 128-lane rows, so the panel is padded here, on the device;
+    the virtual rows are then folded per destination by a sorted
+    segment-sum, whose padding rows land in segment n_dst and are sliced
+    away with the padded lanes."""
     B = x.shape[1]
-    host = (plan.idx, plan.mask, plan.row_dst,
-            np.pad(x, ((0, 0), (0, round_up(B, 128) - B))))
-    dev = jax.block_until_ready([jnp.asarray(a) for a in host])
-    return StagedFrontier(*dev, n_cols=B), sum(a.nbytes for a in host)
+    slots = slots.reshape(row_dst.shape[0], -1)
+    if use_kernel:
+        xp = jnp.pad(x, ((0, 0), (0, round_up(B, LANES) - B)))
+        rows = gather_rows_sum(slots, xp, interpret=interpret,
+                               name="frontier_expand")
+    else:
+        rows = frontier_expand_ref(jnp.maximum(slots, 0), slots >= 0, x)
+    seg = jax.ops.segment_sum(rows, row_dst, num_segments=n_dst + 1,
+                              indices_are_sorted=True)
+    return seg[:n_dst, :B]
 
 
-def expand_staged(plan: FrontierPlan, staged: StagedFrontier,
-                  use_kernel=None, interpret=None) -> np.ndarray:
-    """Second half of `frontier_expand_counts`: launch the expansion and
-    the segment-sum over staged operands and read the counts back."""
+def expand_staged(dplan: DevicePlan, x, use_kernel=None,
+                  interpret=None) -> np.ndarray:
+    """Launch the expansion of a staged panel over a device plan and read
+    the counts back."""
     if use_kernel is None:
         # the Mosaic kernel is the TPU path; off-TPU it would run in
         # interpret mode (a correctness tool, ~1000x slow) — the jit'd ref
         # K-loop is the device-less default
         use_kernel = not default_interpret()
-    if use_kernel:
-        rows = frontier_expand_pallas(staged.idx, staged.mask, staged.x,
-                                      interpret=interpret)
-    else:
-        rows = frontier_expand_ref(staged.idx, staged.mask, staged.x)
-    # virtual rows are destination-sorted; padding rows land in segment
-    # n_dst and are sliced away
-    seg = jax.ops.segment_sum(rows, staged.row_dst,
-                              num_segments=plan.n_dst + 1,
-                              indices_are_sorted=True)
-    return np.asarray(seg[:plan.n_dst, :staged.n_cols])
+    return np.asarray(frontier_expand_launch(
+        dplan.slots, dplan.row_dst, x, n_dst=dplan.n_dst,
+        use_kernel=bool(use_kernel), interpret=interpret))
 
 
 def frontier_expand_counts(plan: FrontierPlan, x, use_kernel=None,
@@ -131,6 +156,8 @@ def frontier_expand_counts(plan: FrontierPlan, x, use_kernel=None,
     """out (n_dst, B): out[d, j] = Σ_{(s,d) in plan} x[s, j]. With 0/1
     indicator columns this is each destination's count of DISTINCT frontier
     in-neighbors — expand + distinct + aggregate in one launch. float32
-    accumulation is integer-exact below 2**24, far above any degree here."""
-    staged, _ = stage_frontier(plan, x)
-    return expand_staged(plan, staged, use_kernel, interpret)
+    accumulation is integer-exact below 2**24, far above any degree here.
+    Uploads the plan on every call; hold an `upload_plan` result and call
+    `expand_staged` to launch over a resident one."""
+    staged, _ = stage_frontier(x)
+    return expand_staged(upload_plan(plan), staged, use_kernel, interpret)

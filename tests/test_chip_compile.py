@@ -17,13 +17,18 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.common import round_up
 from repro.kernels.embedding_bag import embedding_bag_pallas
+from repro.kernels.frontier_expand import frontier_expand_launch
 from repro.kernels.frontier_expand.frontier_expand import frontier_expand_pallas
 from repro.kernels.segment_ell import segment_ell_pallas
 
 # chip_smoke.py's store: 2^21 vertices, ~2^25 edges -> ~3.1M virtual rows
 SMOKE_ROWS = 3_145_728
 SMOKE_VERTICES = 1 << 21
+# the Graph500 scale-20 BFS cell's plan (bench/configs/graph500-s20.json)
+BFS_ROWS = 1_479_296
+BFS_VERTICES = 1 << 20
 
 
 @pytest.fixture(scope="module")
@@ -69,6 +74,24 @@ def test_frontier_expand_compiles(one_chip, rows, vertices):
     mem = compiled.memory_analysis()
     # the panel and the output are the program's only large buffers
     assert mem.output_size_in_bytes == rows * 128 * 4
+
+
+@pytest.mark.parametrize("rows,vertices,cols",
+                         [(BFS_ROWS, BFS_VERTICES, 1), (256, 1000, 130)],
+                         ids=["bfs", "small"])
+def test_frontier_launch_compiles(one_chip, rows, vertices, cols):
+    """The whole launch over a resident plan: the unpadded panel is padded
+    to whole lane tiles on the device, expanded and folded per
+    destination, and only (vertices, cols) counts come out."""
+    compiled = _assert_mosaic(
+        frontier_expand_launch,
+        _sds((rows * 32,), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip),
+        _sds((vertices, cols), jnp.float32, one_chip),
+        n_dst=vertices, use_kernel=True, interpret=False)
+    # the device tiles the counts' layout, but never to the padded lanes
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes < vertices * round_up(cols, 128) * 4
 
 
 def test_segment_ell_compiles(one_chip):

@@ -207,14 +207,17 @@ class TestFrontierExpand:
         npo = frontier_expand_np(plan.idx, plan.mask, xp)
         np.testing.assert_allclose(npo, np.asarray(ref), rtol=1e-5, atol=1e-5)
 
+    # B = 1 and 5 fill part of one 128-lane tile, 128 all of it, 130 spills
+    # into a second: the launch pads the panel on the device
+    @pytest.mark.parametrize("b", [1, 5, 128, 130])
     @pytest.mark.parametrize("use_kernel", [False, True])
-    def test_counts_match_dedup_matmul(self, use_kernel):
+    def test_counts_match_dedup_matmul(self, use_kernel, b):
         rng = np.random.default_rng(7)
         n, e = 220, 3000
         src = rng.integers(0, n, e)
         dst = rng.integers(0, n, e)
         plan = build_frontier_plan(src, dst, n, n)
-        x = (rng.random((n, 5)) < 0.3).astype(np.float32)
+        x = (rng.random((n, b)) < 0.3).astype(np.float32)
         got = frontier_expand_counts(plan, x, use_kernel=use_kernel,
                                      interpret=True)
         a = np.zeros((n, n), np.float32)

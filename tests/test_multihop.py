@@ -384,26 +384,94 @@ class TestFrontierPlan:
         assert out.shape == (10, 2) and not out.any()
 
 
+def _counter_delta(before, after, name):
+    return after.get(name, 0) - before.get(name, 0)
+
+
 class TestKernelUploadCounter:
     @pytest.mark.parametrize("query", ["khop", "two_hop"])
     def test_h2d_bytes_are_the_staged_arrays(self, query):
-        """`multihop.kernel.h2d_bytes` adds, per launch, the `.nbytes` of
-        the plan's arrays and of the indicator panel padded to 128 lanes."""
+        """`multihop.kernel.h2d_bytes` adds the plan's folded slots and
+        `row_dst` once, at the first launch over the plan, and each
+        launch's (M, B) float32 indicator panel unpadded;
+        `multihop.kernel.plan_uploads` rises by exactly one."""
         t = build_messy_lsm(110, 400, 3)
         plan = mh.dense_plan(t, "out")
         M = as_engine(t).n_internal_vertices
-        per_launch = (plan.idx.nbytes + plan.mask.nbytes
-                      + plan.row_dst.nbytes + M * 128 * 4)
+        # the slots are the (R, K) int32 idx with the mask folded in
+        once = plan.idx.nbytes + plan.row_dst.nbytes
         before = telemetry.snapshot()["counters"]
         if query == "khop":
             khop(t, [5], 4, dense="kernel")
         else:
             two_hop_counts(t, np.arange(10), dense="kernel")
         after = telemetry.snapshot()["counters"]
-        launches = (after["multihop.hops"].get("kernel", 0)
-                    - before.get("multihop.hops", {}).get("kernel", 0)
-                    if query == "khop" else 2)
+        if query == "khop":
+            launches = (after["multihop.hops"].get("kernel", 0)
+                        - before.get("multihop.hops", {}).get("kernel", 0))
+            panels = launches * M * 1 * 4
+        else:
+            launches = 2                  # both hops of one 10-seed block
+            panels = launches * M * 10 * 4
         assert launches > 0
-        sent = (after["multihop.kernel.h2d_bytes"]
-                - before.get("multihop.kernel.h2d_bytes", 0))
-        assert sent == launches * per_launch
+        assert _counter_delta(before, after,
+                              "multihop.kernel.h2d_bytes") == once + panels
+        assert _counter_delta(before, after,
+                              "multihop.kernel.plan_uploads") == 1
+
+    def test_pinned_view_uploads_its_plan_once(self):
+        """Two kernel traversals over one pinned view send the plan once;
+        the second sends nothing but its indicator panels."""
+        t = build_messy_lsm(110, 400, 4)
+        with t.read_view() as view:
+            M = as_engine(view).n_internal_vertices
+            first = khop(view, [5], 4, dense="kernel")
+            before = telemetry.snapshot()["counters"]
+            second = khop(view, [5], 4, dense="kernel")
+            after = telemetry.snapshot()["counters"]
+        launches = (after["multihop.hops"].get("kernel", 0)
+                    - before.get("multihop.hops", {}).get("kernel", 0))
+        assert launches > 0
+        assert _counter_delta(before, after,
+                              "multihop.kernel.plan_uploads") == 0
+        assert _counter_delta(before, after,
+                              "multihop.kernel.h2d_bytes") == launches * M * 4
+        for a, b in zip(first.levels, second.levels):
+            assert np.array_equal(a, b)
+
+    def test_new_epoch_uploads_a_new_plan(self):
+        """After `insert_edges`, a new read view's first kernel hop uploads
+        a fresh plan, and its answer matches the sparse path bitwise on the
+        new epoch, the new edge included."""
+        n = 110
+        t = build_messy_lsm(n, 400, 5)
+        with t.read_view() as old:
+            khop(old, [5], 4, dense="kernel")
+        live = set(as_engine(t).out_neighbors_batch([5])[0].tolist())
+        fresh_dst = next(v for v in range(n) if v != 5 and v not in live)
+        t.insert_edges(np.array([5]), np.array([fresh_dst]))
+        with t.read_view() as view:
+            before = telemetry.snapshot()["counters"]
+            dense = khop(view, [5], 4, dense="kernel")
+            after = telemetry.snapshot()["counters"]
+            sparse = khop(view, [5], 4, dense="never")
+        assert _counter_delta(before, after,
+                              "multihop.kernel.plan_uploads") == 1
+        assert len(dense.levels) == len(sparse.levels)
+        for a, b in zip(dense.levels, sparse.levels):
+            assert np.array_equal(a, b)
+        assert np.array_equal(dense.visited, sparse.visited)
+        assert fresh_dst in dense.levels[1].tolist()
+
+    def test_live_store_keeps_one_device_plan(self):
+        """On a live store a mutation moves the cache token: the next
+        kernel hop replaces the older token's device plan, so the cache
+        holds one device plan per direction, not one per epoch."""
+        t = build_messy_lsm(110, 400, 6)
+        eng = as_engine(t)
+        khop(t, [5], 4, dense="kernel")
+        t.insert_edges(np.array([5]), np.array([7]))
+        khop(t, [5], 4, dense="kernel")
+        held = [k for k in eng.plan_cache()
+                if k[0] == (mh._DEVICE_PLAN_KEY, "out")]
+        assert held == [((mh._DEVICE_PLAN_KEY, "out"), eng.cache_token())]
