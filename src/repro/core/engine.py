@@ -71,10 +71,13 @@ class EdgeBatch:
 @dataclasses.dataclass
 class EdgeChunk:
     """One physical slab of live edges in INTERNAL IDs — what bottom-up
-    sweeps and degree passes stream instead of branching on storage class."""
+    sweeps and degree passes stream instead of branching on storage class.
+    `column` holds one named attribute column's values, edge for edge,
+    where the stream was asked for one."""
 
     src: np.ndarray
     dst: np.ndarray
+    column: Optional[np.ndarray] = None
 
 
 # ---------------------------------------------------------------------------
@@ -197,16 +200,22 @@ class _PartitionSlab:
         col = self.part.columns.get(name)
         return None if col is None else col.dtype
 
-    def chunk(self) -> Optional[EdgeChunk]:
+    def chunk(self, column: Optional[str] = None) -> Optional[EdgeChunk]:
         part = self.part
         if part.n_edges == 0:
             return None
         if self.io is not None:  # sequential whole-slab scan: src + dst
             self.io.account_range(0, part.n_edges, 16)
+        vals = None
+        if column is not None:
+            vals = _whole_column(part.columns, column, part.n_edges)
+            if self.io is not None:
+                self.io.account_range(0, part.n_edges, vals.dtype.itemsize)
         if part.dead is None or not part.dead.any():
-            return EdgeChunk(part.src, part.dst)
+            return EdgeChunk(part.src, part.dst, vals)
         live = ~part.dead
-        return EdgeChunk(part.src[live], part.dst[live])
+        return EdgeChunk(part.src[live], part.dst[live],
+                         None if vals is None else vals[live])
 
 
 class _BufferSlab:
@@ -252,10 +261,20 @@ class _BufferSlab:
         col = self.st.columns.get(name)
         return None if col is None else col.dtype
 
-    def chunk(self) -> Optional[EdgeChunk]:
-        if self.st.src.shape[0] == 0:
+    def chunk(self, column: Optional[str] = None) -> Optional[EdgeChunk]:
+        st = self.st
+        if st.src.shape[0] == 0:
             return None
-        return EdgeChunk(self.st.src, self.st.dst)
+        vals = (None if column is None
+                else _whole_column(st.columns, column, st.src.shape[0]))
+        return EdgeChunk(st.src, st.dst, vals)
+
+
+def _whole_column(columns, name: str, n: int) -> np.ndarray:
+    """A slab's whole attribute column; float32 zeros where the slab has
+    none (the value a positional read gives such an edge)."""
+    col = columns.get(name)
+    return np.zeros(n, np.float32) if col is None else col
 
 
 def _slab_positions(slab, vis: np.ndarray,
@@ -347,11 +366,23 @@ class StorageEngine:
         BEFORE the destination gather, so non-matching edges never
         materialize into the result (only their positions are touched).
         """
+        owner, nb, _ = self._expand(vs, direction, predicate, None)
+        return owner, nb
+
+    def expand_frontier_column(self, vs, name: str, direction: str = "out",
+                               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """`expand_frontier` with one attribute column read at the same
+        slab positions: (owner index into vs, neighbor, value) triples, the
+        value float32 zero where a slab lacks the column (weighted
+        traversals, core/multihop.py `sssp`)."""
+        return self._expand(vs, direction, None, name)
+
+    def _expand(self, vs, direction, predicate, column):
         vs = np.asarray(vs, dtype=np.int64).ravel()
         iv = self.intervals
         vis = np.asarray(iv.to_internal(vs))
         release = getattr(self.graph, "release_slab", None)
-        vals, owners = [], []
+        vals, owners, cols = [], [], []
         for slab in self._slabs():
             pos, owner = _slab_positions(slab, vis, direction)
             if pos.size and predicate is not None:
@@ -361,15 +392,20 @@ class StorageEngine:
                 vals.append(slab.dst_at(pos) if direction == "out"
                             else slab.src_at(pos))
                 owners.append(owner)
+                if column is not None:
+                    cols.append(slab.column_at(column, pos, np.float32))
             if release is not None:
                 part = getattr(slab, "part", None)
                 if part is not None:
                     release(part)
         if not vals:
-            return np.empty(0, np.int64), np.empty(0, np.int64)
+            empty = np.empty(0, np.int64)
+            return empty, empty, (None if column is None
+                                  else np.empty(0, np.float32))
         flat = np.concatenate(vals)
         return (np.concatenate(owners),
-                np.asarray(iv.to_original(flat), np.int64))
+                np.asarray(iv.to_original(flat), np.int64),
+                None if column is None else np.concatenate(cols))
 
     def out_degree_batch(self, vs) -> np.ndarray:
         return self._degree_batch(vs, "out")
@@ -508,10 +544,12 @@ class StorageEngine:
         )
 
     # -- whole-store streaming (bottom-up sweeps, degree passes) -------------
-    def edge_chunks(self) -> Iterator[EdgeChunk]:
-        """Stream every live edge once, slab by slab, in internal IDs."""
+    def edge_chunks(self, column: Optional[str] = None
+                    ) -> Iterator[EdgeChunk]:
+        """Stream every live edge once, slab by slab, in internal IDs, with
+        the attribute column `column` beside them where one is named."""
         for slab in self._slabs():
-            chunk = slab.chunk()
+            chunk = slab.chunk(column)
             if chunk is not None and chunk.src.shape[0]:
                 yield chunk
 

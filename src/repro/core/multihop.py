@@ -31,6 +31,11 @@ copies and packed edge-key sets are memoized on the engine's `plan_cache()`
 keyed by `cache_token()`, so a `ManifestView` shares them across every
 reader of one publication and a mutated store can never serve a stale plan.
 
+`sssp` is the weighted traversal: single-source shortest paths over an
+edge-weight column, a frontier-driven Bellman-Ford whose dense rounds run
+a min-plus relax over a weighted plan on the device (DESIGN.md §10,
+"Weighted traversal").
+
 All operators speak only the `StorageEngine` protocol — they run identically
 on a live `LSMTree`, a bulk `GraphPAL`, an mmap-backed `GraphDB`, and a
 lock-free `ManifestView` epoch snapshot.
@@ -44,11 +49,13 @@ from typing import Any, Optional, Sequence
 import numpy as np
 
 from . import telemetry
-from .engine import StorageEngine, _expand_ranges, as_engine
+from .engine import EdgeChunk, StorageEngine, _expand_ranges, as_engine
 
 _M_HOPS = telemetry.counter("multihop.hops")
 _M_H2D = telemetry.counter("multihop.kernel.h2d_bytes")
 _M_PLAN_UPLOADS = telemetry.counter("multihop.kernel.plan_uploads")
+_M_RELAX_ROUNDS = telemetry.counter("multihop.relax.rounds")
+_M_RELAX_IMPROVED = telemetry.counter("multihop.relax.improved")
 
 GraphLike = Any
 
@@ -62,6 +69,7 @@ __all__ = [
     "expand",
     "khop",
     "semijoin",
+    "sssp",
     "triangle_count",
     "two_hop_counts",
 ]
@@ -70,6 +78,8 @@ __all__ = [
 # panels resident; past this vertex count the panel alone would dwarf the
 # frontier work, so `dense="auto"` never picks the kernel path above it
 DENSE_MAX_VERTICES = 4_000_000
+# frontiers above this share of |V| are dense (direction-optimizing BFS's)
+_DENSE_THRESHOLD = 0.05
 _SEED_BLOCK = 128  # dense 2-hop: one kernel feature-tile of seed columns
 
 
@@ -201,7 +211,11 @@ def _expand_stream(eng: StorageEngine, frontier: np.ndarray,
 # ---------------------------------------------------------------------------
 _PLAN_KEY = "multihop:dense_plan"
 _DEVICE_PLAN_KEY = "multihop:dense_plan_device"
+_WPLAN_KEY = "multihop:weighted_plan"
+_DEVICE_WPLAN_KEY = "multihop:weighted_plan_device"
 _EDGE_KEYS = "multihop:edge_keys"
+_NO_EDGES = EdgeChunk(np.empty(0, np.int64), np.empty(0, np.int64),
+                      np.empty(0, np.float32))
 
 
 def _memoized(eng: StorageEngine, name, builder):
@@ -235,11 +249,22 @@ def _edge_keys_internal(eng: StorageEngine) -> np.ndarray:
     return _memoized(eng, _EDGE_KEYS, build)
 
 
-def dense_plan(g: GraphLike, direction: str = "out"):
+def _plan_name(direction: str, weight: Optional[str] = None):
+    """The memo name of a host plan: `(_PLAN_KEY, direction)`, or for the
+    weighted plan over column `weight`, `(_WPLAN_KEY, weight, direction)`."""
+    if weight is None:
+        return (_PLAN_KEY, direction)
+    return (_WPLAN_KEY, weight, direction)
+
+
+def dense_plan(g: GraphLike, direction: str = "out",
+               weight: Optional[str] = None):
     """Build (or fetch the memoized) frontier-expansion plan: the store's
     deduplicated edge set as destination-grouped virtual-row ELL tiles
     (kernels/frontier_expand). `direction="in"` builds the transposed
-    plan."""
+    plan. With `weight`, the weighted plan `sssp` relaxes over: the same
+    layout with each distinct edge's least value of that attribute column
+    per slot, in float32."""
     eng = as_engine(g)
     M = eng.n_internal_vertices
     if M > DENSE_MAX_VERTICES:
@@ -249,33 +274,37 @@ def dense_plan(g: GraphLike, direction: str = "out"):
 
     def build():
         from ..kernels.frontier_expand import build_frontier_plan
-        keys = _edge_keys_internal(eng)
-        s = keys // M
-        d = keys % M
+        if weight is not None:
+            chunks = list(eng.edge_chunks(weight)) or [_NO_EDGES]
+            s, d, w = (np.concatenate(parts) for parts in
+                       zip(*[(c.src, c.dst, c.column) for c in chunks]))
+            w = _weights(w, weight)
+        else:
+            keys = _edge_keys_internal(eng)
+            s, d, w = keys // M, keys % M, None
         if direction == "out":
-            return build_frontier_plan(s, d, n_src=M, n_dst=M)
-        return build_frontier_plan(d, s, n_src=M, n_dst=M)
+            return build_frontier_plan(s, d, n_src=M, n_dst=M, weight=w)
+        return build_frontier_plan(d, s, n_src=M, n_dst=M, weight=w)
 
-    return _memoized(eng, (_PLAN_KEY, direction), build)
+    return _memoized(eng, _plan_name(direction, weight), build)
 
 
-def _plan_cached(eng: StorageEngine, direction: str) -> bool:
+def _plan_cached(eng: StorageEngine, name) -> bool:
     token = eng.cache_token()
-    return (token is not None
-            and ((_PLAN_KEY, direction), token) in eng.plan_cache())
+    return token is not None and (name, token) in eng.plan_cache()
 
 
-def _device_plan(eng: StorageEngine, plan, direction: str):
+def _device_plan(eng: StorageEngine, plan, name):
     """`plan` on the device: uploaded at the first kernel launch over it
-    and memoized beside it, under the same token (call under the
-    `multihop.kernel.prep` span)."""
+    and memoized beside it under the device name `name`, under the same
+    token (call under the `multihop.kernel.prep` span)."""
     def build():
         from ..kernels.frontier_expand import upload_plan
         dplan = upload_plan(plan)
         _M_H2D.inc(dplan.nbytes)
         _M_PLAN_UPLOADS.inc()
         return dplan
-    return _memoized(eng, (_DEVICE_PLAN_KEY, direction), build)
+    return _memoized(eng, name, build)
 
 
 def _stage(x):
@@ -301,7 +330,7 @@ def _expand_dense(eng: StorageEngine, frontier: np.ndarray,
     plan = dense_plan(eng, direction)
     iv = eng.intervals
     with telemetry.span("multihop.kernel.prep"):
-        dplan = _device_plan(eng, plan, direction)
+        dplan = _device_plan(eng, plan, (_DEVICE_PLAN_KEY, direction))
         x = np.zeros((eng.n_internal_vertices, 1), np.float32)
         x[np.asarray(iv.to_internal(frontier), np.int64), 0] = 1.0
         staged = _stage(x)
@@ -311,14 +340,16 @@ def _expand_dense(eng: StorageEngine, frontier: np.ndarray,
 
 
 def _hop_mode(eng: StorageEngine, frontier_size: int, dense: str,
-              threshold: float, predicate) -> str:
+              threshold: float, predicate,
+              plan=_plan_name("out")) -> str:
     """The density heuristic (§10.3). Predicates force the sparse path —
     pushdown only exists in the slab scan. Below `threshold · |V|` the
     frontier is sparse: per-slab searchsorted probes touch only adjacent
-    edges. Above it, every edge is worth a look: use the Pallas plan when
-    one is already memoized for this store content (repeated analytics
-    amortized it) and the store is small enough to hold indicator panels;
-    otherwise a one-shot bottom-up edge stream, which needs no prep."""
+    edges. Above it, every edge is worth a look: use the device when the
+    plan the round needs (`plan`, a `_plan_name`) is already memoized for
+    this store content (repeated analytics amortized it) and the store is
+    small enough to hold indicator panels; otherwise a one-shot bottom-up
+    edge stream, which needs no prep."""
     if predicate is not None or dense == "never":
         return "sparse"
     supported = getattr(eng, "supported_hop_modes",
@@ -330,7 +361,7 @@ def _hop_mode(eng: StorageEngine, frontier_size: int, dense: str,
         return dense if dense in supported else "sparse"
     if frontier_size <= threshold * eng.n_internal_vertices:
         return "sparse"
-    if ("kernel" in supported and _plan_cached(eng, "out")
+    if ("kernel" in supported and _plan_cached(eng, plan)
             and eng.n_internal_vertices <= DENSE_MAX_VERTICES):
         return "kernel"
     return "stream" if "stream" in supported else "sparse"
@@ -357,7 +388,7 @@ class KHopResult:
 
 def khop(g: GraphLike, seeds, k: int, direction: str = "out",
          predicate: Optional[EdgePredicate] = None, dense: str = "auto",
-         dense_threshold: float = 0.05) -> KHopResult:
+         dense_threshold: float = _DENSE_THRESHOLD) -> KHopResult:
     """Whole-frontier k-hop expansion. Each hop expands the previous level
     in ONE engine call (or one kernel launch / edge stream, per the density
     heuristic), then subtracts the visited set and merges — all columnar.
@@ -393,6 +424,114 @@ def khop(g: GraphLike, seeds, k: int, direction: str = "out",
         levels.append(fresh)
         frontier = fresh
     return KHopResult(levels, visited)
+
+
+# ---------------------------------------------------------------------------
+# Single-source shortest paths: frontier Bellman-Ford with min-plus rounds
+# ---------------------------------------------------------------------------
+def _weights(w: np.ndarray, name: str) -> np.ndarray:
+    """An edge-weight column as the float32 the relax adds; a negative
+    weight is refused (it could lower distances forever)."""
+    w = np.asarray(w, np.float32)
+    if w.size and bool((w < 0).any()):
+        raise ValueError(f"edge column {name!r} holds a negative weight")
+    return w
+
+
+def _relax_sparse(eng: StorageEngine, dist: np.ndarray,
+                  frontier: np.ndarray, weight: str,
+                  direction: str) -> np.ndarray:
+    """Host round: the frontier's adjacent edges and their weights off the
+    slab probes, one float32 add each, the least per destination (under
+    the `multihop.probe` span, as a sparse `khop` hop)."""
+    iv = eng.intervals
+    with telemetry.span("multihop.probe"):
+        owner, nb, w = eng.expand_frontier_column(
+            np.asarray(iv.to_original(frontier), np.int64), weight,
+            direction)
+        cand = np.full(dist.shape[0], np.inf, np.float32)
+        np.minimum.at(cand, np.asarray(iv.to_internal(nb), np.int64),
+                      dist[frontier][owner] + _weights(w, weight))
+    return cand
+
+
+def _relax_stream(eng: StorageEngine, dist: np.ndarray,
+                  frontier: np.ndarray, weight: str,
+                  direction: str) -> np.ndarray:
+    """Bottom-up round: every live edge and its weight streamed once, the
+    edges leaving the frontier relaxed."""
+    x = np.full(dist.shape[0], np.inf, np.float32)
+    x[frontier] = dist[frontier]
+    cand = np.full(dist.shape[0], np.inf, np.float32)
+    for chunk in eng.edge_chunks(weight):
+        key, other = ((chunk.src, chunk.dst) if direction == "out"
+                      else (chunk.dst, chunk.src))
+        c = x[key] + _weights(chunk.column, weight)
+        m = c < np.inf
+        np.minimum.at(cand, other[m], c[m])
+    return cand
+
+
+def _relax_dense(eng: StorageEngine, dist: np.ndarray,
+                 frontier: np.ndarray, weight: str,
+                 direction: str) -> np.ndarray:
+    """Device round: the frontier's distances (+inf elsewhere) as a
+    one-column panel, one min-plus relax launch over the resident weighted
+    plan, the candidates read back."""
+    from ..kernels.frontier_expand import relax_staged
+    plan = dense_plan(eng, direction, weight)
+    with telemetry.span("multihop.kernel.prep"):
+        dplan = _device_plan(eng, plan, (_DEVICE_WPLAN_KEY, weight,
+                                         direction))
+        x = np.full((dist.shape[0], 1), np.inf, np.float32)
+        x[frontier, 0] = dist[frontier]
+        staged = _stage(x)
+    with telemetry.span("multihop.kernel.wait"):
+        return relax_staged(dplan, staged)[:, 0]
+
+
+_RELAX = {"kernel": _relax_dense, "stream": _relax_stream,
+          "sparse": _relax_sparse}
+
+
+def sssp(g: GraphLike, root: int, weight: str = "w",
+         direction: str = "out", dense: str = "auto") -> np.ndarray:
+    """Single-source shortest paths from `root` over the non-negative edge
+    column `weight`: float32 distances indexed by original vertex id, +inf
+    where a vertex is unreached (an edge without the column weighs 0).
+
+    Frontier-driven Bellman-Ford: each round relaxes the edges leaving the
+    vertices whose distance fell in the round before, with one float32
+    add per edge and a min per destination, until no distance falls. The
+    round's mode follows `khop`'s density rule (`_hop_mode`) against the
+    weighted plan: sparse rounds probe the slabs with the column on the
+    host, dense ones run the min-plus relax over the resident weighted
+    plan on the device (or stream every edge where none is held). Since
+    weights are non-negative and fl(a + w) is monotone in a and w, the
+    answer is the least float32 fixed point, the least float32 left-to-
+    right path sum, whatever the order of relaxation: every mode gives
+    the same bits."""
+    eng = as_engine(g)
+    iv = eng.intervals
+    M = eng.n_internal_vertices
+    plan = _plan_name(direction, weight)
+    dist = np.full(M, np.inf, np.float32)
+    frontier = np.array([iv.to_internal_scalar(int(root))], np.int64)
+    dist[frontier] = 0.0
+    rnd = 0
+    while frontier.shape[0]:
+        mode = _hop_mode(eng, frontier.shape[0], dense, _DENSE_THRESHOLD,
+                         None, plan)
+        with telemetry.span("multihop.relax", round=rnd, mode=mode,
+                            frontier=int(frontier.shape[0])) as sp:
+            cand = _RELAX[mode](eng, dist, frontier, weight, direction)
+            frontier = np.flatnonzero(cand < dist)
+            dist[frontier] = cand[frontier]
+            sp.tag(improved=int(frontier.shape[0]))
+        _M_RELAX_ROUNDS.inc(label=mode)
+        _M_RELAX_IMPROVED.inc(int(frontier.shape[0]))
+        rnd += 1
+    return dist[np.asarray(iv.to_internal(np.arange(M)), np.int64)]
 
 
 # ---------------------------------------------------------------------------
@@ -498,7 +637,7 @@ def _two_hop_dense(eng: StorageEngine, seeds: np.ndarray, direction: str,
     for c0 in range(0, S, _SEED_BLOCK):
         blk = si[c0:c0 + _SEED_BLOCK]
         with telemetry.span("multihop.kernel.prep"):
-            dplan = _device_plan(eng, plan, direction)
+            dplan = _device_plan(eng, plan, (_DEVICE_PLAN_KEY, direction))
             x = np.zeros((int(M), blk.shape[0]), np.float32)
             x[blk, np.arange(blk.shape[0])] = 1.0
             staged = _stage(x)

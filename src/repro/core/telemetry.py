@@ -133,21 +133,31 @@ CATALOG: Dict[str, Tuple[str, str]] = {
     "multihop.hops": ("counter", "frontier expansions, by mode label"),
     "multihop.hop": ("span", "one k-hop frontier expansion"),
     "multihop.probe": ("span",
-                       "a sparse hop's host slab probes and their dedup"),
+                       "a sparse hop's or relax round's host slab probes "
+                       "and their dedup or per-destination min"),
     "multihop.kernel.prep": ("span",
-                             "one kernel launch's indicator build and "
-                             "upload, plus the plan's upload at the first "
-                             "launch over it"),
+                             "one device launch's panel build (indicators "
+                             "or distances) and upload, plus the plan's "
+                             "upload at the first launch over it"),
     "multihop.kernel.wait": ("span",
-                             "one kernel + segment_sum launch up to the "
-                             "blocking read-back of the counts"),
+                             "one device launch (kernel + segment_sum, or "
+                             "min-plus relax + segment_min) up to the "
+                             "blocking read-back of its result"),
     "multihop.kernel.h2d_bytes": ("counter",
                                   "bytes of host arrays handed to the "
-                                  "device by frontier-expansion launches"),
+                                  "device by frontier-expansion and relax "
+                                  "launches"),
     "multihop.kernel.plan_uploads": ("counter",
-                                     "frontier-expansion plans uploaded to "
-                                     "the device, once per store content "
-                                     "and direction"),
+                                     "frontier-expansion plans, weighted or "
+                                     "not, uploaded to the device, once per "
+                                     "store content, direction and weight"),
+    "multihop.relax": ("span",
+                       "one sssp round: relax the edges leaving the "
+                       "vertices whose distance fell"),
+    "multihop.relax.rounds": ("counter", "sssp relax rounds, by mode label"),
+    "multihop.relax.improved": ("counter",
+                                "vertices whose sssp distance fell in a "
+                                "relax round"),
     "multihop.merge": ("span",
                        "one hop's visited-set subtraction and union"),
     "multihop.two_hop": ("span", "one batched FoF (two_hop_counts) call"),

@@ -1,6 +1,7 @@
 from .ops import (DevicePlan, FrontierPlan, build_frontier_plan,
                   expand_staged, frontier_expand_counts,
-                  frontier_expand_launch, stage_frontier, upload_plan)
+                  frontier_expand_launch, relax_launch, relax_staged,
+                  stage_frontier, upload_plan)
 from .ref import frontier_expand_np, frontier_expand_ref
 
 __all__ = [
@@ -12,6 +13,8 @@ __all__ = [
     "frontier_expand_launch",
     "frontier_expand_np",
     "frontier_expand_ref",
+    "relax_launch",
+    "relax_staged",
     "stage_frontier",
     "upload_plan",
 ]

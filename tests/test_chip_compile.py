@@ -19,14 +19,17 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.common import round_up
 from repro.kernels.embedding_bag import embedding_bag_pallas
-from repro.kernels.frontier_expand import frontier_expand_launch
+from repro.kernels.frontier_expand import (frontier_expand_launch,
+                                           relax_launch)
 from repro.kernels.frontier_expand.frontier_expand import frontier_expand_pallas
 from repro.kernels.segment_ell import segment_ell_pallas
 
 # chip_smoke.py's store: 2^21 vertices, ~2^25 edges -> ~3.1M virtual rows
 SMOKE_ROWS = 3_145_728
 SMOKE_VERTICES = 1 << 21
-# the Graph500 scale-20 BFS cell's plan (bench/configs/graph500-s20.json)
+# the Graph500 scale-20 BFS cell's plan (bench/configs/graph500-s20.json),
+# and the SSSP cell's weighted plan over the same graph
+# (bench/configs/graph500w-s20.json)
 BFS_ROWS = 1_479_296
 BFS_VERTICES = 1 << 20
 
@@ -92,6 +95,22 @@ def test_frontier_launch_compiles(one_chip, rows, vertices, cols):
     # the device tiles the counts' layout, but never to the padded lanes
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes < vertices * round_up(cols, 128) * 4
+
+
+def test_relax_launch_compiles(one_chip):
+    """The min-plus relax over the SSSP cell's resident weighted plan: an
+    XLA gather, add and sorted segment-min, no kernel; (vertices, 1)
+    candidates out, and its scratch a few of the plan's 189 MB columns."""
+    slots = BFS_ROWS * 32
+    compiled = relax_launch.lower(
+        _sds((slots,), jnp.int32, one_chip),
+        _sds((slots,), jnp.float32, one_chip),
+        _sds((BFS_ROWS,), jnp.int32, one_chip),
+        _sds((BFS_VERTICES, 1), jnp.float32, one_chip),
+        n_dst=BFS_VERTICES).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == BFS_VERTICES * 4
+    assert mem.temp_size_in_bytes < 8 * slots * 4
 
 
 def test_segment_ell_compiles(one_chip):

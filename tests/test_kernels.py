@@ -13,7 +13,8 @@ from repro.kernels.common import round_up
 from repro.kernels.frontier_expand import (build_frontier_plan,
                                            frontier_expand_counts,
                                            frontier_expand_np,
-                                           frontier_expand_ref)
+                                           frontier_expand_ref, relax_staged,
+                                           stage_frontier, upload_plan)
 from repro.kernels.frontier_expand.frontier_expand import frontier_expand_pallas
 from repro.kernels.psw_spmm import psw_spmm_edges, spmm_dense_ref
 from repro.kernels.segment_ell import (segment_ell, segment_ell_from_edges,
@@ -230,6 +231,55 @@ class TestFrontierExpand:
         out = frontier_expand_counts(plan, np.ones((10, 3), np.float32),
                                      use_kernel=False)
         assert out.shape == (12, 3) and not out.any()
+
+    @pytest.mark.parametrize("k_slots", [1, 8, 32])
+    def test_weighted_plan_keeps_least_weight_per_edge(self, k_slots):
+        """Each slot's weight is the least over the parallel edges it
+        stands for, +inf in empty slots; the slots, mask and row_dst are
+        bitwise those of the unweighted plan."""
+        rng = np.random.default_rng(k_slots)
+        n, e = 90, 1500
+        src = rng.integers(0, n, e)
+        dst = rng.integers(0, n, e)
+        w = rng.random(e, dtype=np.float32)
+        w[::11] = 0
+        plain = build_frontier_plan(src, dst, n, n, k_slots=k_slots)
+        plan = build_frontier_plan(src, dst, n, n, k_slots=k_slots,
+                                   weight=w)
+        assert plain.weight is None and plan.weight.dtype == np.float32
+        for a, b in ((plain.idx, plan.idx), (plain.mask, plan.mask),
+                     (plain.row_dst, plan.row_dst)):
+            assert np.array_equal(a, b)
+        assert plain.n_edges == plan.n_edges
+        assert np.isinf(plan.weight[~plan.mask]).all()
+        least = {}
+        for s_, d_, w_ in zip(src.tolist(), dst.tolist(), w.tolist()):
+            least[(s_, d_)] = min(least.get((s_, d_), np.inf), w_)
+        rows, cols = np.nonzero(plan.mask)
+        got = {(int(plan.idx[r, c]), int(plan.row_dst[r])):
+               plan.weight[r, c] for r, c in zip(rows, cols)}
+        assert got == least
+
+    @pytest.mark.parametrize("n,e", [(64, 0), (200, 3000), (513, 9000)])
+    def test_relax_matches_min_plus(self, n, e):
+        """One relax launch: per destination, the least x[src] + w over
+        its in-edges from finite x, in float32; +inf where none."""
+        rng = np.random.default_rng(n)
+        src = rng.integers(0, n, e)
+        dst = rng.integers(0, n, e)
+        w = rng.random(e, dtype=np.float32)
+        plan = build_frontier_plan(src, dst, n, n, weight=w)
+        x = np.where(rng.random(n) < 0.3, rng.random(n) * 5,
+                     np.inf).astype(np.float32)[:, None]
+        staged, nbytes = stage_frontier(x)
+        dplan = upload_plan(plan)
+        assert dplan.nbytes == (plan.idx.nbytes + plan.row_dst.nbytes
+                                + plan.weight.nbytes) and nbytes == n * 4
+        got = relax_staged(dplan, staged)
+        want = np.full(n, np.inf, np.float32)
+        np.minimum.at(want, dst, x[src, 0] + w)
+        assert got.shape == (n, 1) and got.dtype == np.float32
+        assert np.array_equal(got[:, 0], want)
 
     @given(st.integers(0, 2**31 - 1))
     @settings(max_examples=10, deadline=None)

@@ -1,7 +1,8 @@
 """Multi-hop operator tests (ISSUE 6): columnar 2-hop / triangle /
 filtered-traversal operators vs naive per-hop references, on messy live LSM
 state (buffers + tombstones), lock-free ManifestView epoch snapshots, the
-dense Pallas plan path, and a reopened on-disk GraphDB."""
+dense Pallas plan path, and a reopened on-disk GraphDB; weighted `sssp`
+against float64 and float32 references in every round mode."""
 import os
 
 import numpy as np
@@ -22,6 +23,7 @@ from repro.core import (
     friends_of_friends_perhop,
     khop,
     shortest_path,
+    sssp,
     triangle_count,
     two_hop_counts,
 )
@@ -475,3 +477,231 @@ class TestKernelUploadCounter:
         held = [k for k in eng.plan_cache()
                 if k[0] == (mh._DEVICE_PLAN_KEY, "out")]
         assert held == [((mh._DEVICE_PLAN_KEY, "out"), eng.cache_token())]
+
+
+# ---------------------------------------------------------------------------
+# Weighted traversal: sssp over an edge-weight column
+# ---------------------------------------------------------------------------
+def weighted_messy_lsm(n, e, seed, n_deletes=0):
+    """A messy live LSM whose edges carry float32 weights in column "w":
+    parallel edges with other weights, zero weights, and vertices no edge
+    reaches (the last tenth of the ids only ever sends)."""
+    rng = np.random.default_rng(seed + 1)
+    w = rng.random(e, dtype=np.float32)
+    w[rng.random(e) < 0.05] = 0
+    t = build_messy_lsm(n, e, seed, n_deletes, columns={"w": w})
+    # parallel copies of some edges, lighter or heavier, and no edge into
+    # the last tenth of the ids
+    live = as_engine(t).edge_columns_batch(np.arange(n), names=["w"])
+    k = min(40, live.src.shape[0])
+    scale = np.where(np.arange(k) % 2 == 0, 0.5, 3.0).astype(np.float32)
+    t.insert_edges(live.src[:k], live.dst[:k],
+                   columns={"w": live.columns["w"][:k] * scale})
+    cut = n - n // 10
+    for s_, d_ in zip(live.src.tolist(), live.dst.tolist()):
+        if d_ >= cut:
+            t.delete_edge(s_, d_)
+    return t
+
+
+def live_weighted_edges(g, n):
+    b = as_engine(g).edge_columns_batch(np.arange(n), names=["w"])
+    return b.src, b.dst, b.columns["w"].astype(np.float32)
+
+
+def bellman_ford32(src, dst, w, n, root):
+    """Plain float32 Bellman-Ford over every edge until nothing falls."""
+    d = np.full(n, np.inf, np.float32)
+    d[root] = 0
+    while True:
+        c = np.full(n, np.inf, np.float32)
+        np.minimum.at(c, dst, d[src] + w)
+        new = np.minimum(d, c)
+        if np.array_equal(new, d):
+            return d
+        d = new
+
+
+def bellman_ford64(src, dst, w, n, root):
+    d = np.full(n, np.inf)
+    d[root] = 0
+    w = w.astype(np.float64)
+    while True:
+        c = np.full(n, np.inf)
+        np.minimum.at(c, dst, d[src] + w)
+        new = np.minimum(d, c)
+        if np.array_equal(new, d):
+            return d
+        d = new
+
+
+class TestSSSP:
+    @pytest.mark.parametrize("seed,e,n_deletes", [(0, 300, 0), (1, 900, 25),
+                                                  (2, 2500, 60)])
+    def test_matches_float64_and_bitwise_float32(self, seed, e, n_deletes):
+        """Against a float64 Bellman-Ford within float32 rounding, and bit
+        for bit against a float32 one, on a live store with buffers,
+        deletes, parallel edges of other weights, zero weights and
+        unreached vertices."""
+        n = 120
+        t = weighted_messy_lsm(n, e, seed, n_deletes)
+        src, dst, w = live_weighted_edges(t, n)
+        assert (w == 0).any() and np.unique(src * n + dst).size < src.size
+        for root in (0, 7, int(src[3])):
+            got = sssp(t, root, dense="never")
+            assert got.dtype == np.float32 and got.shape[0] >= n
+            assert np.isinf(got[n:]).all()
+            want32 = bellman_ford32(src, dst, w, n, root)
+            assert np.array_equal(got[:n], want32)
+            want64 = bellman_ford64(src, dst, w, n, root)
+            reached = np.isfinite(want64)
+            assert np.array_equal(np.isfinite(got[:n]), reached)
+            assert not reached[n - n // 10:].any()
+            # a path of h <= n adds is within h·2^-24 of its float64 sum
+            np.testing.assert_allclose(got[:n][reached], want64[reached],
+                                       rtol=n * 2.0 ** -24, atol=0)
+
+    @pytest.mark.parametrize("seed", [3, 4])
+    def test_every_round_mode_gives_the_same_bits(self, seed):
+        """`dense="kernel"` (the min-plus relax on the device), "stream"
+        and "auto" over a held weighted plan all equal `dense="never"`
+        bitwise, on the live store and on a pinned view."""
+        n = 150
+        t = weighted_messy_lsm(n, 1200, seed, 20)
+        for g in (t, t.read_view()):
+            mh.dense_plan(g, "out", weight="w")
+            for root in (1, 9):
+                base = sssp(g, root, dense="never")
+                for mode in ("kernel", "stream", "auto"):
+                    assert np.array_equal(sssp(g, root, dense=mode), base)
+            base_in = sssp(g, 4, direction="in", dense="never")
+            assert np.array_equal(sssp(g, 4, direction="in",
+                                       dense="kernel"), base_in)
+
+    def test_rounds_run_on_the_device_and_are_counted(self):
+        """With the weighted plan held, dense rounds take the kernel: the
+        relax counters and spans say how many rounds ran in each mode and
+        how many distances fell."""
+        n = 150
+        t = weighted_messy_lsm(n, 1500, 5)
+        with t.read_view() as view:
+            mh.dense_plan(view, "out", weight="w")
+            telemetry.trace_events(clear=True)
+            before = telemetry.snapshot()["counters"]
+            dist = sssp(view, 2)
+            after = telemetry.snapshot()["counters"]
+        rounds = [e for e in telemetry.trace_events(clear=True)
+                  if e["name"] == "multihop.relax"]
+        modes = [e["args"]["mode"] for e in rounds]
+        assert "kernel" in modes and "sparse" in modes
+        ran = {m: after["multihop.relax.rounds"].get(m, 0)
+               - before.get("multihop.relax.rounds", {}).get(m, 0)
+               for m in set(modes)}
+        assert ran == {m: modes.count(m) for m in set(modes)}
+        fell = sum(e["args"]["improved"] for e in rounds)
+        assert _counter_delta(before, after, "multihop.relax.improved") \
+            == fell >= int(np.isfinite(dist).sum()) - 1
+        assert rounds[-1]["args"]["improved"] == 0
+
+    def test_weighted_plan_is_memoized_beside_the_unweighted(self):
+        """The weighted plan and its device copy sit under their own names
+        in the same cache under the same token; holding them changes no
+        BFS hop mode."""
+        n = 150
+        t = weighted_messy_lsm(n, 1500, 6)
+        with t.read_view() as view:
+            eng = as_engine(view)
+            plan = mh.dense_plan(view, "out", weight="w")
+            assert plan.weight is not None
+            bfs_before = khop(view, [2], 5)
+            sssp(view, 2, dense="kernel")
+            token = eng.cache_token()
+            held = {k[0] for k in eng.plan_cache()
+                    if isinstance(k, tuple) and k[1] == token}
+            assert {(mh._WPLAN_KEY, "w", "out"),
+                    (mh._DEVICE_WPLAN_KEY, "w", "out")} <= held
+            assert (mh._PLAN_KEY, "out") not in held
+            # BFS sees no plan of its own: a dense hop streams, as before
+            assert mh._hop_mode(eng, n, "auto", 0.05, None) == "stream"
+            assert mh._hop_mode(eng, n, "auto", 0.05, None,
+                                mh._plan_name("out", "w")) == "kernel"
+            after = khop(view, [2], 5)
+            for a, b in zip(bfs_before.levels, after.levels):
+                assert np.array_equal(a, b)
+
+    def test_pinned_view_is_unchanged_by_mutations(self):
+        n = 120
+        t = weighted_messy_lsm(n, 900, 7, 10)
+        view = t.read_view()
+        mh.dense_plan(view, "out", weight="w")
+        pinned = sssp(view, 0, dense="kernel")
+        live = as_engine(t).edge_columns_batch([0], names=["w"])
+        t.insert_edges(np.arange(1, 30), np.arange(2, 31),
+                       columns={"w": np.zeros(29, np.float32)})
+        for d_ in live.dst[:5].tolist():
+            t.delete_edge(0, int(d_))
+        assert np.array_equal(sssp(view, 0, dense="kernel"), pinned)
+        assert np.array_equal(sssp(view, 0, dense="never"), pinned)
+        view.release()
+        src, dst, w = live_weighted_edges(t, n)
+        with t.read_view() as fresh:
+            assert np.array_equal(sssp(fresh, 0, dense="kernel")[:n],
+                                  bellman_ford32(src, dst, w, n, 0))
+
+    def test_new_epoch_uploads_a_new_weighted_plan(self):
+        """After `insert_edges` of a cheaper edge, a new view's first
+        kernel round uploads a new weighted plan and the distance falls."""
+        n = 120
+        t = weighted_messy_lsm(n, 900, 8)
+        with t.read_view() as old:
+            mh.dense_plan(old, "out", weight="w")
+            before_d = sssp(old, 0, dense="kernel")
+        target = int(np.argmax(np.where(np.isfinite(before_d[:n]),
+                                        before_d[:n], -1)))
+        assert before_d[target] > 0
+        t.insert_edges(np.array([0]), np.array([target]),
+                       columns={"w": np.array([0.0], np.float32)})
+        with t.read_view() as view:
+            before = telemetry.snapshot()["counters"]
+            after_d = sssp(view, 0, dense="kernel")
+            after = telemetry.snapshot()["counters"]
+            assert np.array_equal(after_d, sssp(view, 0, dense="never"))
+        assert _counter_delta(before, after,
+                              "multihop.kernel.plan_uploads") == 1
+        assert after_d[target] == 0 < before_d[target]
+        assert (after_d <= before_d).all()
+
+    def test_durable_store_replays_its_weights(self, tmp_path):
+        """Weights go through the WAL with the edges: a ServiceDB closed
+        with a WAL tail and reopened gives the same distances."""
+        from repro.core import ServiceDB
+        rng = np.random.default_rng(12)
+        n, e = 300, 3000
+        src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
+        w = rng.random(e, dtype=np.float32)
+        d = os.path.join(str(tmp_path), "svc")
+        svc = ServiceDB.create(d, max_id=n - 1, n_partitions=4, n_levels=2,
+                               branching=4, buffer_cap=800,
+                               max_partition_edges=1500,
+                               column_dtypes={"w": "float32"})
+        svc.insert_edges(src[:2500], dst[:2500], columns={"w": w[:2500]})
+        svc.checkpoint()
+        svc.insert_edges(src[2500:], dst[2500:], columns={"w": w[2500:]})
+        with svc.read_view() as view:
+            mh.dense_plan(view, "out", weight="w")
+            want = sssp(view, 5)
+        svc.close()
+        assert np.array_equal(want[:n], bellman_ford32(src, dst, w, n, 5))
+        svc = ServiceDB.open(d)
+        with svc.read_view() as view:
+            assert np.array_equal(sssp(view, 5, dense="kernel"), want)
+        svc.close()
+
+    def test_negative_weight_is_refused(self):
+        n = 60
+        t = build_messy_lsm(n, 200, 9,
+                            columns={"w": np.full(200, -1, np.float32)})
+        with pytest.raises(ValueError, match="negative"):
+            sssp(t, 0, dense="never")
+        with pytest.raises(ValueError, match="negative"):
+            mh.dense_plan(t, "out", weight="w")

@@ -225,9 +225,13 @@ def _tiny_store():
     from repro.core import IntervalMap, LSMTree, dense_plan
     rng = np.random.default_rng(5)
     t = LSMTree(IntervalMap.for_capacity(199, 16), n_levels=3, branching=4,
-                buffer_cap=400, max_partition_edges=800)
-    t.insert_edges(rng.integers(0, 200, 3000), rng.integers(0, 200, 3000))
-    dense_plan(t, "out")   # held: hops over the threshold run the kernel
+                buffer_cap=400, max_partition_edges=800,
+                column_dtypes={"w": np.float32})
+    t.insert_edges(rng.integers(0, 200, 3000), rng.integers(0, 200, 3000),
+                   columns={"w": rng.random(3000, dtype=np.float32)})
+    # held: hops and relax rounds over the threshold run on the device
+    dense_plan(t, "out")
+    dense_plan(t, "out", weight="w")
     return t
 
 
@@ -235,6 +239,15 @@ def _run_khop(t):
     from repro.core import khop
     res = khop(t, [3], 6)
     assert len(res.levels) > 2
+
+
+def _run_sssp(t):
+    from repro.core import sssp
+    assert np.isfinite(sssp(t, 3)).sum() > 100
+
+
+RELAX_CHILDREN = ("multihop.probe", "multihop.kernel.prep",
+                  "multihop.kernel.wait")
 
 
 def _run_pagerank(t):
@@ -296,7 +309,9 @@ class TestProfilerSpans:
         (_run_khop, {"multihop.hop", *HOP_CHILDREN},
          {c: "multihop.hop" for c in HOP_CHILDREN}),
         (_run_pagerank, {"psw.pagerank"}, {}),
-    ], ids=["khop", "pagerank"])
+        (_run_sssp, {"multihop.relax", *RELAX_CHILDREN},
+         {c: "multihop.relax" for c in RELAX_CHILDREN}),
+    ], ids=["khop", "pagerank", "sssp"])
     def test_program_spans_land_on_the_host_plane(self, tmp_path, run,
                                                   spans, parents):
         t = _tiny_store()
