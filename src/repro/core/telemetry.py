@@ -140,9 +140,14 @@ CATALOG: Dict[str, Tuple[str, str]] = {
                              "or distances) and upload, plus the plan's "
                              "upload at the first launch over it"),
     "multihop.kernel.wait": ("span",
-                             "one device launch (kernel + segment_sum, or "
-                             "min-plus relax + segment_min) up to the "
-                             "blocking read-back of its result"),
+                             "one device launch (one-column gather or "
+                             "kernel + segment_sum, or min-plus relax + "
+                             "segment_min) up to the blocking read-back "
+                             "of its result"),
+    "multihop.kernel.launches": ("counter",
+                                 "frontier-expansion launches, by path "
+                                 "label: gather (one-column panel), mosaic "
+                                 "(the Pallas kernel) or ref (jnp K-loop)"),
     "multihop.kernel.h2d_bytes": ("counter",
                                   "bytes of host arrays handed to the "
                                   "device by frontier-expansion and relax "

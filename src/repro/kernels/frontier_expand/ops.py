@@ -12,6 +12,9 @@ plan is exact and costs (|E|/k + n_present_dsts) rows.
 padding rows map to `n_dst` so one sorted segment-sum both reduces the
 virtual rows and discards padding.
 
+A one-column panel (a single-root hop) skips the kernel: one flat XLA
+gather of a value per slot, summed per virtual row, is the whole launch.
+
 A weighted plan carries one float32 weight per slot (the least over
 parallel edges) in the same layout, for the min-plus relax of
 single-source shortest paths (`relax_launch`): gather each slot's source
@@ -28,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...core import telemetry
 from ..common import default_interpret, round_up
 from .frontier_expand import LANES, gather_rows_sum
 from .ref import frontier_expand_ref
@@ -36,6 +40,8 @@ __all__ = ["DevicePlan", "FrontierPlan", "build_frontier_plan",
            "expand_staged", "frontier_expand_counts",
            "frontier_expand_launch", "relax_launch", "relax_staged",
            "stage_frontier", "upload_plan"]
+
+_M_LAUNCHES = telemetry.counter("multihop.kernel.launches")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,12 +163,23 @@ def stage_frontier(x):
 def frontier_expand_launch(slots, row_dst, x, *, n_dst: int,
                            use_kernel: bool, interpret=None):
     """One expansion as one program: (n_dst, B) counts of the (n_src, B)
-    panel x over a device plan's flat slots and `row_dst`. The kernel
-    takes whole 128-lane rows, so the panel is padded here, on the device;
-    the virtual rows are then folded per destination by a sorted
-    segment-sum, whose padding rows land in segment n_dst and are sliced
-    away with the padded lanes."""
+    panel x over a device plan's flat slots and `row_dst`. The virtual
+    rows are folded per destination by a sorted segment-sum, whose padding
+    rows land in segment n_dst and are sliced away.
+
+    A one-column panel (B = 1, a single-root hop) is a flat XLA gather of
+    one value per slot, as in `relax_launch`, whatever `use_kernel` says:
+    the kernel would fetch a whole 128-lane row per slot for one lane.
+    Wider panels take the kernel, which reads whole 128-lane rows, so the
+    panel is padded here, on the device; or, without `use_kernel`, the
+    jnp K-loop."""
     B = x.shape[1]
+    if B == 1:
+        v = jnp.where(slots >= 0, x[:, 0][jnp.maximum(slots, 0)], 0.0)
+        rows = v.reshape(row_dst.shape[0], -1).sum(axis=1)
+        seg = jax.ops.segment_sum(rows, row_dst, num_segments=n_dst + 1,
+                                  indices_are_sorted=True)
+        return seg[:n_dst, None]
     slots = slots.reshape(row_dst.shape[0], -1)
     if use_kernel:
         xp = jnp.pad(x, ((0, 0), (0, round_up(B, LANES) - B)))
@@ -203,12 +220,14 @@ def relax_staged(dplan: DevicePlan, x) -> np.ndarray:
 def expand_staged(dplan: DevicePlan, x, use_kernel=None,
                   interpret=None) -> np.ndarray:
     """Launch the expansion of a staged panel over a device plan and read
-    the counts back."""
+    the counts back, counting the launch under the path it takes."""
     if use_kernel is None:
         # the Mosaic kernel is the TPU path; off-TPU it would run in
         # interpret mode (a correctness tool, ~1000x slow) — the jit'd ref
         # K-loop is the device-less default
         use_kernel = not default_interpret()
+    _M_LAUNCHES.inc(label="gather" if x.shape[1] == 1
+                    else "mosaic" if use_kernel else "ref")
     return np.asarray(frontier_expand_launch(
         dplan.slots, dplan.row_dst, x, n_dst=dplan.n_dst,
         use_kernel=bool(use_kernel), interpret=interpret))

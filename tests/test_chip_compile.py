@@ -83,17 +83,25 @@ def test_frontier_expand_compiles(one_chip, rows, vertices):
                          [(BFS_ROWS, BFS_VERTICES, 1), (256, 1000, 130)],
                          ids=["bfs", "small"])
 def test_frontier_launch_compiles(one_chip, rows, vertices, cols):
-    """The whole launch over a resident plan: the unpadded panel is padded
-    to whole lane tiles on the device, expanded and folded per
-    destination, and only (vertices, cols) counts come out."""
-    compiled = _assert_mosaic(
-        frontier_expand_launch,
+    """The whole launch over a resident plan, and only (vertices, cols)
+    counts out. A one-column panel (the BFS cell's single-root hops) is a
+    flat XLA gather and sorted segment-sum, no kernel, its scratch a few
+    of the plan's slot columns; a wider one is padded to whole lane tiles
+    on the device and expanded by the Mosaic kernel."""
+    compiled = frontier_expand_launch.lower(
         _sds((rows * 32,), jnp.int32, one_chip),
         _sds((rows,), jnp.int32, one_chip),
         _sds((vertices, cols), jnp.float32, one_chip),
-        n_dst=vertices, use_kernel=True, interpret=False)
-    # the device tiles the counts' layout, but never to the padded lanes
+        n_dst=vertices, use_kernel=True, interpret=False).compile()
     mem = compiled.memory_analysis()
+    if cols == 1:
+        assert "tpu_custom_call" not in compiled.as_text()
+        out = compiled.out_info
+        assert out.shape == (vertices, 1) and out.dtype == jnp.float32
+        assert mem.temp_size_in_bytes < 8 * rows * 32 * 4
+    else:
+        assert "tpu_custom_call" in compiled.as_text()
+    # the device tiles the counts' layout, but never to the padded lanes
     assert mem.output_size_in_bytes < vertices * round_up(cols, 128) * 4
 
 

@@ -1,5 +1,7 @@
 """Per-kernel allclose tests vs ref.py oracles: shape/dtype sweeps +
 hypothesis property tests (interpret mode on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ from repro.kernels.flash_attention import (attention_ref, flash_attention,
 from repro.kernels.common import round_up
 from repro.kernels.frontier_expand import (build_frontier_plan,
                                            frontier_expand_counts,
+                                           frontier_expand_launch,
                                            frontier_expand_np,
                                            frontier_expand_ref, relax_staged,
                                            stage_frontier, upload_plan)
@@ -224,6 +227,50 @@ class TestFrontierExpand:
         a = np.zeros((n, n), np.float32)
         a[dst, src] = 1.0  # dedup: multi-edges count once
         np.testing.assert_allclose(got, a @ x, rtol=1e-5, atol=1e-5)
+
+    # a one-column launch is the flat gather on every backend: it equals
+    # the numpy K-loop folded per destination bit for bit, and traces no
+    # kernel, whatever use_kernel says
+    @pytest.mark.parametrize("case", ["hub", "sparse_slots", "vertex0",
+                                      "empty_frontier", "full_frontier"])
+    @pytest.mark.parametrize("use_kernel", [False, True])
+    def test_one_column_launch_is_the_oracle_bitwise(self, use_kernel, case):
+        rng = np.random.default_rng(17)
+        n, k = 300, 8
+        if case == "hub":
+            # every vertex sends to vertex 3, four times over: 300
+            # distinct in-neighbours, 38 virtual rows of one destination
+            src = np.concatenate([rng.permutation(n).repeat(4),
+                                  rng.integers(0, n, 900)])
+            dst = np.concatenate([np.full(4 * n, 3),
+                                  rng.integers(0, n, 900)])
+        else:
+            # in-degree about 2 in rows of 32 slots: most slots empty,
+            # and padding rows past the last destination
+            src, dst = rng.integers(0, n, 600), rng.integers(0, n, 600)
+            k = 32
+        plan = build_frontier_plan(src, dst, n, n, k_slots=k)
+        assert (~plan.mask).any() and (plan.row_dst == n).any()
+        x = np.zeros((n, 1), np.float32)
+        if case == "full_frontier":
+            x[:] = 1.0
+        elif case != "empty_frontier":
+            x[rng.choice(n, n // 5, replace=False)] = 1.0
+        if case == "vertex0":
+            # empty slots read x[0] through max(slots, 0) and count 0
+            x[0] = 1.0
+        got = frontier_expand_counts(plan, x, use_kernel=use_kernel,
+                                     interpret=True)
+        rows = frontier_expand_np(plan.idx, plan.mask, x)
+        want = np.zeros((n + 1, 1), np.float32)
+        np.add.at(want, plan.row_dst, rows)
+        assert got.shape == (n, 1) and got.dtype == np.float32
+        assert np.array_equal(got, want[:n])
+        dplan = upload_plan(plan)
+        jaxpr = str(jax.make_jaxpr(functools.partial(
+            frontier_expand_launch, n_dst=n, use_kernel=use_kernel,
+            interpret=True))(dplan.slots, dplan.row_dst, x))
+        assert "pallas_call" not in jaxpr
 
     def test_empty_plan(self):
         plan = build_frontier_plan(np.empty(0, np.int64), np.empty(0, np.int64),

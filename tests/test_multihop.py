@@ -478,6 +478,37 @@ class TestKernelUploadCounter:
                 if k[0] == (mh._DEVICE_PLAN_KEY, "out")]
         assert held == [((mh._DEVICE_PLAN_KEY, "out"), eng.cache_token())]
 
+    def test_launches_are_counted_by_path(self):
+        """`multihop.kernel.launches` counts a single-root BFS's kernel
+        hops, one each, under `gather`; a 128-seed dense 2-hop's two
+        launches are wide panels and count none there (`mosaic` on the
+        chip, `ref` off it), and its answer is the sparse path's."""
+        t = build_messy_lsm(200, 900, 7)
+
+        def labels(snap, name="multihop.kernel.launches"):
+            c = snap.get(name, {})
+            return c if isinstance(c, dict) else {"": c}
+
+        before = telemetry.snapshot()["counters"]
+        khop(t, [5], 6, dense="kernel")
+        mid = telemetry.snapshot()["counters"]
+        hops = (labels(mid, "multihop.hops").get("kernel", 0)
+                - labels(before, "multihop.hops").get("kernel", 0))
+        assert hops > 0
+        assert (labels(mid).get("gather", 0)
+                - labels(before).get("gather", 0)) == hops
+        seeds = np.arange(128)
+        dense = two_hop_counts(t, seeds, dense="kernel")
+        after = telemetry.snapshot()["counters"]
+        wide = {k: v - labels(mid).get(k, 0)
+                for k, v in labels(after).items()}
+        assert wide.get("gather", 0) == 0
+        assert wide.get("mosaic", 0) + wide.get("ref", 0) == 2
+        sparse = two_hop_counts(t, seeds, dense="never")
+        for a, b in ((dense.ids, sparse.ids), (dense.counts, sparse.counts),
+                     (dense.offsets, sparse.offsets)):
+            assert np.array_equal(a, b)
+
 
 # ---------------------------------------------------------------------------
 # Weighted traversal: sssp over an edge-weight column
