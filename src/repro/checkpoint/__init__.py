@@ -1,1 +1,1 @@
-from .manager import CheckpointManager, restore_lsm, save_lsm
+from .manager import restore_lsm, save_lsm

@@ -1,21 +1,12 @@
-"""Sharded, immutable, resumable checkpoints.
+"""Incremental LSM graph checkpoints.
 
 Design (mirrors the paper's crash-integrity argument, §7.3): every artifact
 is an immutable flat file; a checkpoint is a manifest pointing at files; the
 manifest is written LAST via atomic rename, so a crash mid-save can never
 corrupt a restorable state — at worst the newest checkpoint is absent and
-the previous manifest still points at complete files.
-
-Features:
-  * pytree save/restore as npz (one file per step by default; per-shard
-    splitting hook for multi-host),
-  * async save (background thread) so the train loop doesn't stall,
-  * elastic re-shard on restore: arrays come back as host numpy and are
-    device_put with WHATEVER sharding the new mesh dictates — N→M data
-    parallel resize needs no conversion step,
-  * LSM graph checkpoints are INCREMENTAL: partitions are immutable, so only
-    partitions not already in the store are written (content-addressed by
-    (level, index, n_edges, hash)).
+the previous one still points at complete files. Partitions are immutable,
+so only partitions not already in the checkpoint are written
+(content-addressed by a hash of their edges).
 """
 from __future__ import annotations
 
@@ -23,141 +14,16 @@ import hashlib
 import json
 import os
 import shutil
-import threading
-import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict
 
 import numpy as np
-
-import jax
 
 from ..core.failpoints import failpoint
 from ..core.integrity import fsync_dir
 
-__all__ = ["CheckpointManager", "save_lsm", "restore_lsm"]
+__all__ = ["save_lsm", "restore_lsm"]
 
 
-def _flatten_with_paths(tree):
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    items = {}
-    for path, leaf in flat:
-        key = "/".join(str(p) for p in path)
-        items[key] = np.asarray(leaf)
-    return items, treedef
-
-
-class CheckpointManager:
-    def __init__(self, directory: str, keep: int = 3):
-        self.dir = directory
-        self.keep = keep
-        os.makedirs(directory, exist_ok=True)
-        self._thread: Optional[threading.Thread] = None
-
-    # -- manifest helpers ------------------------------------------------------
-    def _manifest_path(self) -> str:
-        return os.path.join(self.dir, "MANIFEST.json")
-
-    def _read_manifest(self) -> Dict[str, Any]:
-        p = self._manifest_path()
-        if not os.path.exists(p):
-            return {"checkpoints": []}
-        with open(p) as f:
-            return json.load(f)
-
-    def _write_manifest(self, m: Dict[str, Any]) -> None:
-        tmp = self._manifest_path() + ".tmp"
-        failpoint("manifest.write")
-        with open(tmp, "w") as f:
-            json.dump(m, f, indent=1)
-            f.flush()
-            os.fsync(f.fileno())
-        failpoint("manifest.rename")
-        os.replace(tmp, self._manifest_path())      # atomic
-        fsync_dir(self.dir)
-
-    # -- save/restore ----------------------------------------------------------
-    def save(self, step: int, tree, blocking: bool = True) -> str:
-        """Save a pytree snapshot for `step`."""
-        host_tree = jax.tree.map(lambda x: np.asarray(x), tree)
-
-        def _do():
-            fname = f"step_{step:010d}.npz"
-            fpath = os.path.join(self.dir, fname)
-            items, _ = _flatten_with_paths(host_tree)
-            tmp = fpath + ".tmp"
-            with open(tmp, "wb") as f:       # file handle: no .npz suffixing
-                np.savez(f, **items)
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, fpath)           # atomic publish
-            fsync_dir(self.dir)
-            m = self._read_manifest()
-            m["checkpoints"] = [c for c in m["checkpoints"] if c["step"] != step]
-            m["checkpoints"].append({"step": step, "file": fname,
-                                     "time": time.time()})
-            m["checkpoints"].sort(key=lambda c: c["step"])
-            while len(m["checkpoints"]) > self.keep:
-                old = m["checkpoints"].pop(0)
-                try:
-                    os.remove(os.path.join(self.dir, old["file"]))
-                except OSError:
-                    pass
-            self._write_manifest(m)
-
-        if blocking:
-            _do()
-        else:
-            self.wait()
-            self._thread = threading.Thread(target=_do, daemon=True)
-            self._thread.start()
-        return os.path.join(self.dir, f"step_{step:010d}.npz")
-
-    def wait(self) -> None:
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-
-    def latest_step(self) -> Optional[int]:
-        m = self._read_manifest()
-        if not m["checkpoints"]:
-            return None
-        return m["checkpoints"][-1]["step"]
-
-    def restore(self, template, step: Optional[int] = None,
-                shardings=None):
-        """Restore into the structure of `template`. With `shardings` (a
-        pytree of jax.sharding.Sharding or None), arrays are device_put
-        accordingly — elastic re-shard to any new mesh."""
-        self.wait()
-        if step is None:
-            step = self.latest_step()
-        if step is None:
-            raise FileNotFoundError("no checkpoints in " + self.dir)
-        m = self._read_manifest()
-        entry = next(c for c in m["checkpoints"] if c["step"] == step)
-        data = np.load(os.path.join(self.dir, entry["file"]))
-        items, treedef = _flatten_with_paths(template)
-        restored = {}
-        for key, tmpl in items.items():
-            raw = data[key]
-            if raw.dtype != tmpl.dtype:
-                # ml_dtypes (bfloat16 etc.) come back as raw void bytes —
-                # reinterpret with the template's dtype
-                raw = (raw.view(tmpl.dtype) if raw.dtype.kind == "V"
-                       else raw.astype(tmpl.dtype))
-            restored[key] = raw
-        leaves = [restored[k] for k in items]
-        tree = jax.tree.unflatten(treedef, leaves)
-        if shardings is not None:
-            tree = jax.tree.map(
-                lambda x, s: jax.device_put(x, s) if s is not None else jax.device_put(x),
-                tree, shardings)
-        return tree, step
-
-
-# ---------------------------------------------------------------------------
-# Incremental LSM graph checkpoints (immutability → only new partitions hit disk)
-# ---------------------------------------------------------------------------
 def _partition_digest(part) -> str:
     h = hashlib.sha1()
     h.update(np.ascontiguousarray(part.src).tobytes())

@@ -1,2 +1,2 @@
 from .linkbench import LinkBenchConfig, LinkBenchWorkload, REQUEST_MIX
-from .pipeline import GraphStream, TokenStream, TokenStreamConfig
+from .pipeline import GraphStream

@@ -1,51 +1,9 @@
-"""Data pipeline: synthetic token stream + LSM-segment shuffle buffer.
-
-The token pipeline mirrors the paper's ingestion discipline: data arrives in
-IMMUTABLE segments (the LSM level-0 analogue); a bounded shuffle buffer merges
-segments; batches are deterministic functions of (seed, step) so a restarted
-job reproduces the exact stream from any checkpointed step — the data-side
-half of fault tolerance.
-"""
+"""Synthetic edge streams for the ingestion benchmarks."""
 from __future__ import annotations
-
-import dataclasses
-from typing import Dict, Iterator, Optional
 
 import numpy as np
 
-__all__ = ["TokenStreamConfig", "TokenStream", "GraphStream"]
-
-
-@dataclasses.dataclass(frozen=True)
-class TokenStreamConfig:
-    vocab_size: int
-    batch: int
-    seq_len: int
-    seed: int = 0
-
-
-class TokenStream:
-    """Deterministic synthetic LM batches; `batch_at(step)` is random-access
-    (restart-safe — no iterator state to lose)."""
-
-    def __init__(self, cfg: TokenStreamConfig):
-        self.cfg = cfg
-
-    def batch_at(self, step: int) -> Dict[str, np.ndarray]:
-        rng = np.random.default_rng((self.cfg.seed << 32) ^ step)
-        # zipf-ish marginal over the vocab = realistic token frequencies
-        z = rng.zipf(1.3, size=(self.cfg.batch, self.cfg.seq_len + 1))
-        toks = (z - 1) % self.cfg.vocab_size
-        return {
-            "tokens": toks[:, :-1].astype(np.int32),
-            "labels": toks[:, 1:].astype(np.int32),
-        }
-
-    def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
-        step = 0
-        while True:
-            yield self.batch_at(step)
-            step += 1
+__all__ = ["GraphStream"]
 
 
 class GraphStream:

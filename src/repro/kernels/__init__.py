@@ -1,6 +1,7 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""The device kernels of the store's analytics path (interpret mode on CPU).
 
-Each kernel package ships <name>.py (pl.pallas_call + BlockSpec tiling),
-ops.py (jit'd public wrapper), and ref.py (pure-jnp oracle).
+One package, `frontier_expand`: frontier_expand.py (the Mosaic row-DMA
+gather, `pl.pallas_call` + BlockSpec tiling), ops.py (the plan builder and
+the jit'd launches over a resident plan) and ref.py (the jnp and numpy
+oracles). common.py holds the backend test and the rounding helpers.
 """
-from . import embedding_bag, flash_attention, psw_spmm, segment_ell

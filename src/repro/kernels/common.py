@@ -10,9 +10,5 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def cdiv(a: int, b: int) -> int:
-    return -(-a // b)
-
-
 def round_up(a: int, b: int) -> int:
-    return cdiv(a, b) * b
+    return -(-a // b) * b

@@ -2,11 +2,11 @@
 
 Virtual-row ELL: the deduplicated edge set, grouped by destination, is
 split into rows of at most `k_slots` sources — a destination of degree d
-occupies ceil(d/k) rows, so the plan is linear in |E|. Compare the two
-existing device layouts at 1M+ edges: `psw_spmm`'s dense tiles materialize
-O(n_blocks²·B²) memory, and `pad_to_ell` pads every vertex to the max
-degree (quadratic-ish on power-law tails, and truncating). The virtual-row
-plan is exact and costs (|E|/k + n_present_dsts) rows.
+occupies ceil(d/k) rows, so the plan is linear in |E|. Dense (dst block,
+src block) tiles would materialize O(n_blocks²·B²) memory at 1M+ edges,
+and padding every vertex to the largest degree grows with the power-law
+tail (and truncates at a cap). The virtual-row plan is exact and costs
+(|E|/k + n_present_dsts) rows.
 
 `row_dst` maps each virtual row to its destination, destination-sorted;
 padding rows map to `n_dst` so one sorted segment-sum both reduces the
@@ -183,8 +183,7 @@ def frontier_expand_launch(slots, row_dst, x, *, n_dst: int,
     slots = slots.reshape(row_dst.shape[0], -1)
     if use_kernel:
         xp = jnp.pad(x, ((0, 0), (0, round_up(B, LANES) - B)))
-        rows = gather_rows_sum(slots, xp, interpret=interpret,
-                               name="frontier_expand")
+        rows = gather_rows_sum(slots, xp, interpret=interpret)
     else:
         rows = frontier_expand_ref(jnp.maximum(slots, 0), slots >= 0, x)
     seg = jax.ops.segment_sum(rows, row_dst, num_segments=n_dst + 1,

@@ -1,4 +1,5 @@
-"""Compile every Pallas kernel of the tree for a described TPU v5e chip.
+"""Compile the Pallas kernel and the device launches of the store's
+analytics path for a described TPU v5e chip.
 
 The TPU compiler is installed here even where no chip is attached: it
 compiles for a topology that is only described, and refuses what Mosaic
@@ -17,12 +18,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.psw import DeviceGraph, pagerank_device
 from repro.kernels.common import round_up
-from repro.kernels.embedding_bag import embedding_bag_pallas
 from repro.kernels.frontier_expand import (frontier_expand_launch,
                                            relax_launch)
 from repro.kernels.frontier_expand.frontier_expand import frontier_expand_pallas
-from repro.kernels.segment_ell import segment_ell_pallas
 
 # chip_smoke.py's store: 2^21 vertices, ~2^25 edges -> ~3.1M virtual rows
 SMOKE_ROWS = 3_145_728
@@ -32,6 +32,13 @@ SMOKE_VERTICES = 1 << 21
 # (bench/configs/graph500w-s20.json)
 BFS_ROWS = 1_479_296
 BFS_VERTICES = 1 << 20
+# the twitter-2010 cells (bench/configs/twitter2010-s20.json): the BFS
+# cell's plan, and one PageRank snapshot's layout, 16 intervals of 2^16
+# vertices with 2,465,536 padded in-edges and 44,416-row windows each
+TWITTER_ROWS = 1_720_960
+TWITTER_VERTICES = 1 << 20
+TWITTER_PARTITIONS, TWITTER_INTERVAL = 16, 1 << 16
+TWITTER_EDGES, TWITTER_WINDOW = 2_465_536, 44_416
 
 
 @pytest.fixture(scope="module")
@@ -80,8 +87,10 @@ def test_frontier_expand_compiles(one_chip, rows, vertices):
 
 
 @pytest.mark.parametrize("rows,vertices,cols",
-                         [(BFS_ROWS, BFS_VERTICES, 1), (256, 1000, 130)],
-                         ids=["bfs", "small"])
+                         [(BFS_ROWS, BFS_VERTICES, 1),
+                          (TWITTER_ROWS, TWITTER_VERTICES, 1),
+                          (256, 1000, 130)],
+                         ids=["bfs", "twitter_bfs", "small"])
 def test_frontier_launch_compiles(one_chip, rows, vertices, cols):
     """The whole launch over a resident plan, and only (vertices, cols)
     counts out. A one-column panel (the BFS cell's single-root hops) is a
@@ -121,18 +130,40 @@ def test_relax_launch_compiles(one_chip):
     assert mem.temp_size_in_bytes < 8 * slots * 4
 
 
-def test_segment_ell_compiles(one_chip):
-    _assert_mosaic(segment_ell_pallas,
-                   _sds((256, 16), jnp.int32, one_chip),
-                   _sds((256, 16), jnp.bool_, one_chip),
-                   _sds((512, 256), jnp.float32, one_chip),
-                   interpret=False)
+def test_seed_block_launch_compiles(one_chip):
+    """A full 128-seed panel (the dense 2-hop's block) over the Graph500
+    cell's plan takes the Mosaic kernel: no lane padding, and (vertices,
+    128) counts out."""
+    compiled = frontier_expand_launch.lower(
+        _sds((BFS_ROWS * 32,), jnp.int32, one_chip),
+        _sds((BFS_ROWS,), jnp.int32, one_chip),
+        _sds((BFS_VERTICES, 128), jnp.float32, one_chip),
+        n_dst=BFS_VERTICES, use_kernel=True, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes == \
+        BFS_VERTICES * 128 * 4
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_embedding_bag_compiles(one_chip, dtype):
-    _assert_mosaic(embedding_bag_pallas,
-                   _sds((256, 8), jnp.int32, one_chip),
-                   _sds((256, 8), jnp.float32, one_chip),
-                   _sds((1000, 128), dtype, one_chip),
-                   interpret=False)
+def test_pagerank_sweep_compiles(one_chip):
+    """`pagerank_device`'s scan of window-exchange sweeps (the default
+    mode) at one twitter-2010 snapshot's shapes: XLA ops only, (P, L)
+    float32 ranks out, and the whole program in a v5e's 16 GB."""
+    P, L = TWITTER_PARTITIONS, TWITTER_INTERVAL
+    E, W = TWITTER_EDGES, TWITTER_WINDOW
+
+    def run(src, dst_local, mask, outdeg, send_idx, edge_owner, edge_slot):
+        return pagerank_device(DeviceGraph(
+            P, L, P * E, src, dst_local, mask, outdeg, send_idx,
+            edge_owner, edge_slot))
+
+    compiled = jax.jit(run).lower(
+        _sds((P, E), jnp.int32, one_chip), _sds((P, E), jnp.int32, one_chip),
+        _sds((P, E), jnp.bool_, one_chip), _sds((P, L), jnp.int32, one_chip),
+        _sds((P, P, W), jnp.int32, one_chip),
+        _sds((P, E), jnp.int32, one_chip),
+        _sds((P, E), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == P * L * 4
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 16 * 10 ** 9

@@ -219,9 +219,9 @@ class TestEngineGenericQueries:
 
 
 class TestSnapshot:
-    """Acceptance: LSMTree.snapshot() feeds PSW sweeps / psw_spmm tiles with
-    results identical to the GraphPAL-built DeviceGraph, including edges
-    still sitting in buffers."""
+    """Acceptance: LSMTree.snapshot() feeds PSW sweeps with results
+    identical to the GraphPAL-built DeviceGraph, including edges still
+    sitting in buffers."""
 
     def test_snapshot_bit_identical_to_pal(self):
         t, src, dst, _ = build_live_lsm(n_deletes=0, seed=7)
@@ -252,8 +252,9 @@ class TestSnapshot:
         assert t.total_buffered() > 0 and dg.n_edges == t.n_edges
 
     def test_snapshot_spmm_on_live_store(self):
-        """FoF-as-SpMM / Pallas tiles directly against the online store."""
-        from repro.kernels.psw_spmm import psw_spmm_edges, spmm_dense_ref
+        """FoF-as-SpMM: one edge-centric sweep of the live store's
+        snapshot, in both gather modes, is the dense SpMV of its COO."""
+        from repro.core.psw import edge_centric_sweep
         import jax.numpy as jnp
 
         rng = np.random.default_rng(10)
@@ -267,8 +268,20 @@ class TestSnapshot:
         t.insert_edges(src[2700:], dst[2700:])  # < cap: stays buffered
         assert t.total_buffered() > 0
         s, d = t.to_coo()
-        x = jnp.asarray(rng.normal(size=(n, 16)).astype(np.float32))
-        out = psw_spmm_edges(s, d, x, n, block=128)
-        ref = spmm_dense_ref(jnp.asarray(src), jnp.asarray(dst), x, n)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)
+        assert sorted(zip(s.tolist(), d.tolist())) == \
+            sorted(zip(src.tolist(), dst.tolist()))
+        dg = t.snapshot()
+        x = rng.normal(size=(n, 16)).astype(np.float32)
+        # vertex state in the device layout: row = internal id
+        rows = np.asarray(iv.to_internal(np.arange(n)), np.int64)
+        xi = np.zeros((iv.max_vertices, 16), np.float32)
+        xi[rows] = x
+        ref = np.zeros((n, 16), np.float64)
+        np.add.at(ref, d, x[s].astype(np.float64))
+        for mode in ("psw_windows", "dense_gather"):
+            out = edge_centric_sweep(
+                dg, jnp.asarray(xi.reshape(dg.n_partitions,
+                                           dg.interval_len, 16)),
+                lambda v: v, mode)
+            out = np.asarray(out).reshape(-1, 16)[rows]
+            np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)

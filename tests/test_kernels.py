@@ -1,5 +1,5 @@
-"""Per-kernel allclose tests vs ref.py oracles: shape/dtype sweeps +
-hypothesis property tests (interpret mode on CPU)."""
+"""The frontier-expansion kernel and its launches vs the ref.py oracles:
+shape sweeps + hypothesis property tests (interpret mode on CPU)."""
 import functools
 
 import jax
@@ -8,183 +8,16 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.embedding_bag import embedding_bag, embedding_bag_ref
-from repro.kernels.flash_attention import (attention_ref, flash_attention,
-                                           flash_attention_pallas)
 from repro.kernels.common import round_up
 from repro.kernels.frontier_expand import (build_frontier_plan,
+                                           expand_staged,
                                            frontier_expand_counts,
                                            frontier_expand_launch,
                                            frontier_expand_np,
                                            frontier_expand_ref, relax_staged,
                                            stage_frontier, upload_plan)
-from repro.kernels.frontier_expand.frontier_expand import frontier_expand_pallas
-from repro.kernels.psw_spmm import psw_spmm_edges, spmm_dense_ref
-from repro.kernels.segment_ell import (segment_ell, segment_ell_from_edges,
-                                       segment_ell_ref)
-
-
-class TestPswSpmm:
-    @pytest.mark.parametrize("n,e,f", [(100, 500, 16), (300, 3000, 64),
-                                       (513, 4000, 130), (64, 64, 256)])
-    def test_matches_edge_oracle(self, n, e, f):
-        rng = np.random.default_rng(n + e)
-        src = rng.integers(0, n, e)
-        dst = rng.integers(0, n, e)
-        x = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
-        out = psw_spmm_edges(src, dst, x, n, block=128)
-        ref = spmm_dense_ref(jnp.asarray(src), jnp.asarray(dst), x, n)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_empty_dst_blocks_zeroed(self):
-        # all edges target node 0 — other blocks must still be initialized
-        src = np.arange(50)
-        dst = np.zeros(50, np.int64)
-        x = jnp.ones((300, 8), jnp.float32)
-        out = psw_spmm_edges(src, dst, x, 300, block=128)
-        assert float(jnp.abs(out[1:]).max()) == 0.0
-        np.testing.assert_allclose(np.asarray(out[0]), 50.0)
-
-    @given(st.integers(0, 2**31 - 1), st.integers(1, 300),
-           st.sampled_from([1, 8, 40, 128]))
-    @settings(max_examples=10, deadline=None)
-    def test_property_random_graphs(self, seed, e, f):
-        rng = np.random.default_rng(seed)
-        n = int(rng.integers(2, 400))
-        src = rng.integers(0, n, e)
-        dst = rng.integers(0, n, e)
-        x = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
-        out = psw_spmm_edges(src, dst, x, n, block=128)
-        ref = spmm_dense_ref(jnp.asarray(src), jnp.asarray(dst), x, n)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)
-
-
-class TestSegmentEll:
-    @pytest.mark.parametrize("n,k,m,f", [(100, 8, 50, 30), (256, 16, 256, 128),
-                                         (33, 5, 20, 200), (128, 1, 10, 128)])
-    def test_matches_oracle(self, n, k, m, f):
-        rng = np.random.default_rng(n * k)
-        idx = jnp.asarray(rng.integers(0, m, (n, k)), jnp.int32)
-        mask = jnp.asarray(rng.random((n, k)) < 0.7)
-        x = jnp.asarray(rng.normal(size=(m, f)).astype(np.float32))
-        out = segment_ell(idx, mask, x)
-        ref = segment_ell_ref(idx, mask, x)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-6, atol=1e-6)
-
-    def test_from_edges_matches_spmm(self):
-        rng = np.random.default_rng(7)
-        n, e, f = 60, 200, 24
-        src = rng.integers(0, n, e)
-        dst = rng.integers(0, n, e)
-        # cap above max in-degree so nothing is dropped
-        x = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
-        out = segment_ell_from_edges(src, dst, x, n, max_degree=e)
-        ref = spmm_dense_ref(jnp.asarray(src), jnp.asarray(dst), x, n)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_all_masked(self):
-        idx = jnp.zeros((128, 4), jnp.int32)
-        mask = jnp.zeros((128, 4), bool)
-        x = jnp.ones((8, 128), jnp.float32)
-        out = segment_ell(idx, mask, x)
-        assert float(jnp.abs(out).max()) == 0.0
-
-
-class TestFlashAttention:
-    @pytest.mark.parametrize("b,s,h,hkv,d", [
-        (1, 128, 2, 2, 64), (2, 256, 4, 2, 64), (2, 256, 8, 1, 128),
-        (1, 512, 4, 4, 128),
-    ])
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_matches_oracle(self, b, s, h, hkv, d, causal):
-        key = jax.random.PRNGKey(b * s + h)
-        ks = jax.random.split(key, 3)
-        q = jax.random.normal(ks[0], (b, s, h, d), jnp.float32)
-        k = jax.random.normal(ks[1], (b, s, hkv, d), jnp.float32)
-        v = jax.random.normal(ks[2], (b, s, hkv, d), jnp.float32)
-        out = flash_attention_pallas(q, k, v, causal=causal)
-        ref = attention_ref(q, k, v, causal=causal)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_bf16(self):
-        key = jax.random.PRNGKey(0)
-        ks = jax.random.split(key, 3)
-        q = jax.random.normal(ks[0], (1, 128, 2, 64), jnp.bfloat16)
-        k = jax.random.normal(ks[1], (1, 128, 2, 64), jnp.bfloat16)
-        v = jax.random.normal(ks[2], (1, 128, 2, 64), jnp.bfloat16)
-        out = flash_attention_pallas(q, k, v, causal=True)
-        ref = attention_ref(q.astype(jnp.float32), k.astype(jnp.float32),
-                            v.astype(jnp.float32), causal=True)
-        np.testing.assert_allclose(np.asarray(out, np.float32),
-                                   np.asarray(ref), rtol=2e-2, atol=2e-2)
-
-    def test_custom_vjp_grads(self):
-        key = jax.random.PRNGKey(1)
-        ks = jax.random.split(key, 3)
-        q = jax.random.normal(ks[0], (1, 128, 2, 64), jnp.float32)
-        k = jax.random.normal(ks[1], (1, 128, 1, 64), jnp.float32)
-        v = jax.random.normal(ks[2], (1, 128, 1, 64), jnp.float32)
-
-        def f(q, k, v):
-            return (flash_attention(q, k, v, True) ** 2).sum()
-
-        def f_ref(q, k, v):
-            return (attention_ref(q, k, v, True) ** 2).sum()
-
-        g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-4)
-
-    def test_cross_attention_longer_kv(self):
-        """Decode-ish: S < T (query block over a longer kv history)."""
-        key = jax.random.PRNGKey(2)
-        ks = jax.random.split(key, 3)
-        q = jax.random.normal(ks[0], (2, 128, 4, 64), jnp.float32)
-        k = jax.random.normal(ks[1], (2, 512, 2, 64), jnp.float32)
-        v = jax.random.normal(ks[2], (2, 512, 2, 64), jnp.float32)
-        out = flash_attention_pallas(q, k, v, causal=False)
-        ref = attention_ref(q, k, v, causal=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-
-class TestEmbeddingBag:
-    @pytest.mark.parametrize("b,k,v,d", [(64, 4, 1000, 32), (128, 16, 500, 64),
-                                         (200, 2, 50, 128), (128, 1, 10, 16)])
-    @pytest.mark.parametrize("mode", ["sum", "mean"])
-    def test_matches_oracle(self, b, k, v, d, mode):
-        rng = np.random.default_rng(b + k)
-        idx = jnp.asarray(rng.integers(0, v, (b, k)), jnp.int32)
-        w = jnp.asarray(rng.random((b, k)).astype(np.float32))
-        table = jnp.asarray(rng.normal(size=(v, d)).astype(np.float32))
-        out = embedding_bag(idx, w, table, mode=mode)
-        ref = embedding_bag_ref(idx, w, table, mode=mode)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
-
-    @given(st.integers(0, 2**31 - 1))
-    @settings(max_examples=10, deadline=None)
-    def test_property_weighted_bags(self, seed):
-        rng = np.random.default_rng(seed)
-        b = int(rng.integers(1, 80))
-        k = int(rng.integers(1, 12))
-        v = int(rng.integers(1, 300))
-        d = int(rng.integers(1, 100))
-        idx = jnp.asarray(rng.integers(0, v, (b, k)), jnp.int32)
-        w = jnp.asarray((rng.random((b, k)) < 0.8).astype(np.float32)
-                        * rng.random((b, k)).astype(np.float32))
-        table = jnp.asarray(rng.normal(size=(v, d)).astype(np.float32))
-        out = embedding_bag(idx, w, table)
-        ref = embedding_bag_ref(idx, w, table)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-4)
+from repro.kernels.frontier_expand.frontier_expand import (
+    frontier_expand_pallas, gather_rows_sum)
 
 
 class TestFrontierExpand:
@@ -271,6 +104,47 @@ class TestFrontierExpand:
             frontier_expand_launch, n_dst=n, use_kernel=use_kernel,
             interpret=True))(dplan.slots, dplan.row_dst, x))
         assert "pallas_call" not in jaxpr
+
+    # K = 1 to 64 slots per row, one or two 128-lane chunks per source row,
+    # every slot full or about half of them empty (-1): the row-DMA gather
+    # adds the occupied rows in slot order in float32, as the numpy K-loop
+    @pytest.mark.parametrize("fill", ["full", "half_empty"])
+    @pytest.mark.parametrize("b", [128, 256])
+    @pytest.mark.parametrize("k", [1, 7, 32, 64])
+    def test_gather_rows_sum_matches_numpy(self, k, b, fill):
+        rng = np.random.default_rng(k * b)
+        r, m = 128, 300
+        slots = rng.integers(0, m, (r, k)).astype(np.int32)
+        if fill == "half_empty":
+            slots[rng.random((r, k)) < 0.5] = -1
+        x = rng.normal(size=(m, b)).astype(np.float32)
+        got = np.asarray(gather_rows_sum(jnp.asarray(slots), jnp.asarray(x),
+                                         interpret=True))
+        want = frontier_expand_np(np.maximum(slots, 0), slots >= 0, x)
+        assert got.shape == (r, b) and got.dtype == np.float32
+        assert np.array_equal(got, want)
+
+    # panel widths on both sides of one and two 128-lane tiles, and a small
+    # one: the counts of a resident plan equal the numpy K-loop folded per
+    # destination, bit for bit (0/1 panels sum exactly in float32)
+    @pytest.mark.parametrize("b", [2, 64, 127, 129, 256])
+    @pytest.mark.parametrize("use_kernel", [False, True])
+    def test_expand_staged_matches_numpy(self, use_kernel, b):
+        rng = np.random.default_rng(b)
+        n, e = 260, 2500
+        src = rng.integers(0, n, e)
+        dst = rng.integers(0, n, e)
+        plan = build_frontier_plan(src, dst, n, n)
+        x = (rng.random((n, b)) < 0.2).astype(np.float32)
+        staged, nbytes = stage_frontier(x)
+        assert nbytes == n * b * 4
+        got = expand_staged(upload_plan(plan), staged, use_kernel=use_kernel,
+                            interpret=True)
+        rows = frontier_expand_np(plan.idx, plan.mask, x)
+        want = np.zeros((n + 1, b), np.float32)
+        np.add.at(want, plan.row_dst, rows)
+        assert got.shape == (n, b) and got.dtype == np.float32
+        assert np.array_equal(got, want[:n])
 
     def test_empty_plan(self):
         plan = build_frontier_plan(np.empty(0, np.int64), np.empty(0, np.int64),

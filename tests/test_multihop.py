@@ -347,7 +347,7 @@ class TestFrontierPlan:
     def test_virtual_rows_linear_in_edges(self):
         from repro.kernels.frontier_expand import build_frontier_plan
         rng = np.random.default_rng(9)
-        # one hub: degree 5000 would make pad_to_ell allocate n*5000 slots
+        # one hub: padding every vertex to degree 5000 would take n*5000 slots
         src = np.concatenate([rng.integers(0, 1000, 5000),
                               rng.integers(0, 1000, 2000)])
         dst = np.concatenate([np.zeros(5000, np.int64),

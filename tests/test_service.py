@@ -318,7 +318,7 @@ class TestCrashRecovery:
         svc.close()
 
 
-class TestCheckpointManager:
+class TestSaveLsm:
     def test_save_lsm_captures_live_buffers(self, tmp_path):
         """checkpoint/manager satellite: save_lsm on a store with unflushed
         buffers restores them, columns included (the old checkpoints
